@@ -21,6 +21,7 @@ from repro.core import collective_matmul as cm          # noqa: E402
 from repro.core import flash_decode as fd               # noqa: E402
 from repro.core import taxes                            # noqa: E402
 from repro.kernels import ops                           # noqa: E402
+from repro.launch.mesh import make_mesh                 # noqa: E402
 from repro.serving.kv_cache import pow2_bucket          # noqa: E402
 
 
@@ -37,7 +38,7 @@ def timeit(fn, *args, iters=20, warmup=3):
 def bench_ag_gemm(W=8):
     """Paper Figure 9: AG+GEMM speedup vs M (K=8192 N=28672 scaled down
     16x for CPU: K=512, N=1792)."""
-    mesh = jax.make_mesh((W,), ("model",))
+    mesh = make_mesh((W,), ("model",))
     K, N = 512, 1792
     b = jax.random.normal(jax.random.PRNGKey(1), (K, N), jnp.float32)
     for M in (16, 64, 256, 1024):
@@ -63,7 +64,7 @@ def bench_ag_gemm(W=8):
 
 def bench_flash_decode(W=8):
     """Paper Figure 10: Flash Decode vs global KV length (evolution)."""
-    mesh = jax.make_mesh((W,), ("model",))
+    mesh = make_mesh((W,), ("model",))
     B, H, KVH, D = 1, 96, 8, 64
     q = jax.random.normal(jax.random.PRNGKey(0), (B, H, D), jnp.float32)
     for S in (4096, 16384, 65536):
@@ -93,7 +94,7 @@ def bench_scaling():
     for W in (1, 2, 4, 8):
         if W > n:
             continue
-        mesh = jax.make_mesh((W,), ("model",))
+        mesh = make_mesh((W,), ("model",))
         k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KVH, D),
                               jnp.bfloat16)
         v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KVH, D),
@@ -294,7 +295,7 @@ def bench_paged_bounded(W=8):
     counts next to the (fake-device, structure-only) wall clock."""
     n = len(jax.devices())
     W = min(W, n)
-    mesh = jax.make_mesh((W,), ("model",))
+    mesh = make_mesh((W,), ("model",))
     B, H, KVH, D = 8, 8, 4, 16
     bs, max_blocks, live = 8, 4, 2
     q = jax.random.normal(jax.random.PRNGKey(0), (B, H, D), jnp.float32)
@@ -687,7 +688,7 @@ def bench_ci(out_path="BENCH_ci.json"):
     Writes BENCH_ci.json and exits nonzero on any violation."""
     n = len(jax.devices())
     W = min(4, n)
-    mesh = jax.make_mesh((W,), ("model",))
+    mesh = make_mesh((W,), ("model",))
     B, H, KVH, D = 4, 8, 4, 16
     bs, max_blocks, live = 8, 4, 2
     n_blocks = B * max_blocks
@@ -787,7 +788,7 @@ def bench_ci(out_path="BENCH_ci.json"):
 
 def bench_pallas_ag_gemm(W=4):
     """Fused in-kernel AG+GEMM (interpret mode: structural check only)."""
-    mesh = jax.make_mesh((W,), ("model",))
+    mesh = make_mesh((W,), ("model",))
     M, K, N = 64, 256, 512
     a = jax.random.normal(jax.random.PRNGKey(0), (M, K), jnp.float32)
     b = jax.random.normal(jax.random.PRNGKey(1), (K, N), jnp.float32)

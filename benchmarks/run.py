@@ -26,7 +26,10 @@ def _table(title):
 
 
 def _sub(which, devices=8):
+    """Run one distributed_bench table on fake CPU devices; returns
+    whether the child succeeded."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env.setdefault("PYTHONPATH", "src")
     out = subprocess.run(
@@ -39,6 +42,7 @@ def _sub(which, devices=8):
     for line in out.stdout.splitlines():
         if "," in line and not line.startswith("#"):
             print(line, flush=True)
+    return out.returncode == 0
 
 
 def table_taxes():
@@ -75,16 +79,19 @@ def table_local_matmul():
 
 
 def main() -> None:
+    ok = []
     _table("table1: AG+GEMM M-sweep (paper Fig. 9)")
-    _sub("ag_gemm")
+    ok.append(_sub("ag_gemm"))
     _table("table2: Flash Decode KV sweep (paper Fig. 10)")
-    _sub("flash_decode")
+    ok.append(_sub("flash_decode"))
     _table("table3: Flash Decode scaling (paper Fig. 11)")
-    _sub("scaling")
+    ok.append(_sub("scaling"))
     table_taxes()
     table_local_matmul()
     _table("pallas fused AG+GEMM (structural, interpret mode)")
-    _sub("pallas", devices=4)
+    ok.append(_sub("pallas", devices=4))
+    if not all(ok):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

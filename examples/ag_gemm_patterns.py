@@ -21,11 +21,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import collective_matmul as cm
 from repro.core import taxes
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 
 def main():
     W = 8
-    mesh = jax.make_mesh((W,), ("model",))
+    mesh = make_mesh((W,), ("model",))
     M, K, N = 128, 1024, 512
     a = jax.random.normal(jax.random.PRNGKey(0), (M, K), jnp.float32)
     b = jax.random.normal(jax.random.PRNGKey(1), (K, N), jnp.float32)

@@ -63,7 +63,7 @@ class ModelConfig:
     remat: bool = True                # activation checkpointing per layer
     remat_policy: str = "full"        # full | dots (save matmul outputs)
     scan_layers: bool = True
-    fusion_mode: str = "auto"         # bsp | ring | pallas | auto
+    fusion_mode: str = "auto"         # auto | bsp | ring
     # per-arch logical-axis remapping (hillclimbed; see EXPERIMENTS.md §Perf)
     sharding_overrides: tuple = ()    # tuple of (logical_axis, mesh_axes)
 

@@ -33,11 +33,10 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-
-from repro.core import jax_compat
 
 
 def _ring_perms(axis: str, W: int):
@@ -54,7 +53,7 @@ def ag_gemm_k_sharded(a, b_full, *, axis: str, mode: str = "ring"):
 
     a: (M, K/W) local shard, b_full: (K, N) replicated.
     """
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     i = lax.axis_index(axis)
     k = a.shape[-1]
     right, left = _ring_perms(axis, W)
@@ -105,7 +104,7 @@ def ag_gemm_m_sharded(a, b, *, axis: str, mode: str = "ring"):
 
     Returns (..., M, N/W): full rows, column shard.
     """
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     i = lax.axis_index(axis)
     right, left = _ring_perms(axis, W)
     mdim = a.ndim - 2
@@ -156,7 +155,7 @@ def gemm_rs(a, b, *, axis: str, mode: str = "ring"):
 
     a: (..., M, K/W), b: (K/W, N). Returns (..., M/W, N).
     """
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     i = lax.axis_index(axis)
     right, _ = _ring_perms(axis, W)
     mdim = a.ndim - 2
@@ -200,7 +199,7 @@ def gemm_rs(a, b, *, axis: str, mode: str = "ring"):
 # Standalone ring all-gather (paper §4.2.3 "Independent All-Gather Kernel").
 # --------------------------------------------------------------------------
 def all_gather_ring(x, *, axis: str, gather_axis: int = 0):
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     i = lax.axis_index(axis)
     right, _ = _ring_perms(axis, W)
     m = x.shape[gather_axis]
@@ -227,9 +226,9 @@ def _smap(fn, mesh, in_specs, out_specs, axis: str, check_vma=True):
     # outputs are *semantically* replicated but computed from per-device
     # shard orders (k-sharded ring, decode combine) opt out — VMA analysis
     # cannot prove their replication.
-    return jax_compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, axis_names={axis},
-                                check_vma=check_vma)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={axis},
+                         check_vma=check_vma)
 
 
 def _check(cond: bool, msg: str):

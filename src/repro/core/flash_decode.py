@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core import jax_compat
-
 
 # ---------------------------------------------------------------- local part
 def local_partial_attention(q, k_shard, v_shard, valid, scale):
@@ -89,7 +87,7 @@ def finalize(partial):
 # --------------------------------------------------------- combine strategies
 def combine_bsp(partial, *, axis: str):
     """Paper baseline: blocking all-gather, then a separate combine pass."""
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     gathered = jax.tree.map(
         lambda x: lax.all_gather(x, axis, axis=0, tiled=False), partial)
     acc = jax.tree.map(lambda x: x[0], gathered)
@@ -100,7 +98,7 @@ def combine_bsp(partial, *, axis: str):
 
 def combine_ring(partial, *, axis: str):
     """Fine-grained: combine each arriving partial while the next flies."""
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     right = [(j, (j + 1) % W) for j in range(W)]
     cur = partial
     acc = partial
@@ -113,7 +111,7 @@ def combine_ring(partial, *, axis: str):
 def combine_rs_ag(partial, *, axis: str):
     """Beyond-paper: reduce-scatter over heads with the combine op, then
     all-gather. O(2·size) wire traffic vs O(W·size) for the ring pass."""
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     H = partial[0].shape[1]
     if H % W != 0:
         return combine_ring(partial, axis=axis)
@@ -150,7 +148,7 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, axis: str,
     cur_len: scalar int32 — tokens (including current) in the cache.
     Returns (B, H, D) attention output, replicated.
     """
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     i = lax.axis_index(axis)
     S_loc = k_cache.shape[1]
     gpos = jnp.arange(S_loc, dtype=jnp.int32) * W + i      # global positions
@@ -181,8 +179,8 @@ def decode_attention_sm(q, k_cache, v_cache, cur_len, mesh, *, axis="model",
     fn = functools.partial(decode_attention, axis=axis, scale=scale,
                            mode=mode, window=window)
     ins = (P(), P(None, axis, None, None), P(None, axis, None, None), P())
-    return jax_compat.shard_map(fn, mesh=mesh, in_specs=ins, out_specs=P(),
-                                axis_names={axis}, check_vma=False)(
+    return jax.shard_map(fn, mesh=mesh, in_specs=ins, out_specs=P(),
+                         axis_names={axis}, check_vma=False)(
         q, k_cache, v_cache, cur_len)
 
 
@@ -208,7 +206,7 @@ def decode_attention_fused(q, k_new, v_new, k_cache, v_cache, cur_len, *,
     so the ownership predicate must not fire for them.
     Returns (out, k_cache, v_cache).
     """
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     i = lax.axis_index(axis)
     S_loc = k_cache.shape[1]
     cl = jnp.asarray(cur_len)
@@ -258,9 +256,9 @@ def decode_attention_fused_sm(q, k_new, v_new, k_cache, v_cache, cur_len,
         args.append(active)
         ins.append(P())
     outs = (P(), cache_spec, cache_spec)
-    return jax_compat.shard_map(fn, mesh=mesh, in_specs=tuple(ins),
-                                out_specs=outs, axis_names={axis},
-                                check_vma=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(ins),
+                         out_specs=outs, axis_names={axis},
+                         check_vma=False)(*args)
 
 
 # --------------------------------------------------- paged (block-granular) KV
@@ -400,7 +398,7 @@ def decode_paged_attention_fused(q, k_new, v_new, k_pool, v_pool, cur_len,
     softmax partial algebra, so they agree to float rounding and decode
     token-identical streams.
     """
-    W = jax_compat.axis_size(axis)
+    W = lax.axis_size(axis)
     i = lax.axis_index(axis)
     n_loc, bs = k_pool.shape[0], k_pool.shape[1]
     B = q.shape[0]
@@ -478,9 +476,9 @@ def decode_paged_attention_fused_sm(q, k_new, v_new, k_pool, v_pool, cur_len,
         args.append(active)
         ins.append(P())
     outs = (P(), pool_spec, pool_spec)
-    return jax_compat.shard_map(fn, mesh=mesh, in_specs=tuple(ins),
-                                out_specs=outs, axis_names={axis},
-                                check_vma=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(ins),
+                         out_specs=outs, axis_names={axis},
+                         check_vma=False)(*args)
 
 
 def reference_paged_decode_attention(q, k_pool, v_pool, cur_len, tables,

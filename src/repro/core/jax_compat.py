@@ -1,64 +1,13 @@
-"""Version compatibility for JAX APIs the codebase depends on.
+"""Backend probe and Pallas interpret switch for the installed JAX.
 
-The repo targets the current ``jax.shard_map`` / Pallas-TPU APIs, but
-must also run on older jax (>= 0.4.3x) where:
-
-* ``shard_map`` lives in ``jax.experimental.shard_map`` and takes
-  ``check_rep`` / ``auto`` instead of ``check_vma`` / ``axis_names``;
-* ``pltpu.InterpretParams`` / ``pltpu.CompilerParams`` don't exist yet
-  (the interpret flag is a plain bool; compiler params are
-  ``pltpu.TPUCompilerParams``).
-
-Everything multi-device goes through :func:`shard_map` here; Pallas
-kernels go through :func:`pallas_interpret` / :func:`tpu_compiler_params`.
+``default_interpret`` is the one place the code asks which backend it
+is on; every Pallas kernel threads its answer through
+``pallas_interpret``.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: public top-level API
-    _shard_map = jax.shard_map
-    _NEW_SHARD_MAP = True
-except AttributeError:  # jax 0.4.x: experimental API
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NEW_SHARD_MAP = False
-
 from jax.experimental.pallas import tpu as pltpu
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool = True):
-    """``jax.shard_map`` with the new keyword surface on every jax.
-
-    ``axis_names`` — mesh axes the body is manual over (defaults to all);
-    ``check_vma``  — replication/varying-manual-axes checking (maps to
-    ``check_rep`` on old jax).
-    """
-    if _NEW_SHARD_MAP:
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-    # Old jax: always full-manual with the rep checker off. Partial-auto
-    # either rejects replicated out_specs (check_rep=True) or lowers
-    # axis_index to a PartitionId the SPMD partitioner refuses
-    # (check_rep=False); full-manual does neither, and axes outside
-    # `axis_names` are replicated per the specs — which is what every
-    # call site's specs already say. Forward AND grads verified against
-    # the single-device oracles under this mapping.
-    del axis_names
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
-def axis_size(axis) -> int:
-    """``lax.axis_size`` on new jax; on old jax ``psum(1, axis)``, which
-    constant-folds to the static axis size inside shard_map bodies."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
 
 
 def default_interpret() -> bool:
@@ -76,42 +25,8 @@ def default_interpret() -> bool:
 
 def pallas_interpret(interpret: bool):
     """Value for ``pl.pallas_call(interpret=...)``: the TPU-interpreter
-    params object where available (eager DMA so ring kernels make
-    progress), else the legacy bool."""
+    params (eager DMA, so ring kernels make progress) when interpreting,
+    else ``False``."""
     if not interpret:
         return False
-    if hasattr(pltpu, "InterpretParams"):
-        return pltpu.InterpretParams(dma_execution_mode="eager")
-    return True
-
-
-def pallas_barrier_supported(interpret: bool) -> bool:
-    """Whether ``pltpu.get_barrier_semaphore`` lowers in this config.
-    The old interpreter has no rule for it; the barrier is a hardware
-    readiness handshake, so interpret-mode runs can safely skip it."""
-    return not interpret or hasattr(pltpu, "InterpretParams")
-
-
-def pallas_device_id(idx):
-    """Remote-DMA / semaphore target for a 1-D logical mesh.
-
-    New Pallas takes a tuple of per-mesh-axis indices; the old
-    interpret-mode discharge rules compare ``device_id`` against a
-    scalar axis index and choke on tuples."""
-    if hasattr(pltpu, "InterpretParams"):
-        return (idx,)
-    return idx
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams`` (old).
-
-    Unknown fields for the installed version are dropped rather than
-    crashing at import/trace time (e.g. ``collective_id`` predates
-    some releases' params object).
-    """
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    fields = getattr(cls, "__dataclass_fields__", None)
-    if fields is not None:
-        kwargs = {k: v for k, v in kwargs.items() if k in fields}
-    return cls(**kwargs)
+    return pltpu.InterpretParams(dma_execution_mode="eager")

@@ -7,8 +7,6 @@ Model code calls :func:`project_up` / :func:`project_down` /
 * ``bsp``   — explicit collective then dot inside shard_map (the paper's
               RCCL baseline, reproduced structurally).
 * ``ring``  — overlapped ring collective-matmul (the paper's technique).
-* ``pallas``— in-kernel remote-DMA Pallas kernels where available,
-              falling back to ``ring`` for shapes the kernels don't cover.
 * ``auto``  — plain einsum + sharding constraints: XLA SPMD decides. This
               is the production default and the *fastest honest baseline*
               (XLA may itself overlap); ``bsp`` exists to reproduce the
@@ -54,7 +52,7 @@ def project_up(x, w, *, seq_axis_sharded: bool = True):
                          *(None,) * (y.ndim - 3), None, "act_mlp")
     if x.shape[-2] % W != 0:  # sequence not divisible: fall back
         return jnp.einsum("...k,kn->...n", x, w.astype(x.dtype))
-    m = "bsp" if mode == "bsp" else ("ring_bidir" if mode in ("ring", "pallas") else "ring")
+    m = "bsp" if mode == "bsp" else ("ring_bidir" if mode == "ring" else "ring")
     return cm.ag_gemm_m_sharded_sm(x, w.astype(x.dtype), ctx.mesh, mode=m)
 
 
@@ -71,7 +69,7 @@ def project_down(x, w):
                          *(None,) * (y.ndim - 3), "seq", None)
     if x.shape[-2] % W != 0:
         return jnp.einsum("...k,kn->...n", x, w.astype(x.dtype))
-    m = "bsp" if mode == "bsp" else ("ring_bidir" if mode in ("ring", "pallas") else "ring")
+    m = "bsp" if mode == "bsp" else ("ring_bidir" if mode == "ring" else "ring")
     return cm.gemm_rs_sm(x, w.astype(x.dtype), ctx.mesh, mode=m)
 
 
@@ -96,8 +94,7 @@ def decode_attn(q, k_cache, v_cache, cur_len, *, scale,
     if W == 1:
         return fd.reference_decode_attention(q, k_cache, v_cache, cur_len,
                                              scale, window)
-    combine = {"bsp": "bsp", "ring": "ring", "pallas": "ring",
-               "auto": "rs_ag"}[mode]
+    combine = {"bsp": "bsp", "ring": "ring", "auto": "rs_ag"}[mode]
     return fd.decode_attention_sm(q, k_cache, v_cache, cur_len, ctx.mesh,
                                   scale=scale, mode=combine, window=window)
 
@@ -110,12 +107,12 @@ def decode_attn_fused(q, k_new, v_new, k_cache, v_cache, cur_len, *, scale,
     shard_map region (see core.flash_decode.decode_attention_fused).
     ``active`` (B,) bool gates the per-slot cache write (continuous
     batching / chunked prefill). Returns (out, k_cache, v_cache). Used
-    for fusion_mode ring/pallas; 'auto'/'bsp' keep the XLA-scatter
+    for fusion_mode ring; 'auto'/'bsp' keep the XLA-scatter
     baseline for comparison."""
     ctx = dctx.current()
     mode = _mode(ctx)
-    combine = {"ring": "ring", "pallas": "ring", "rs_ag": "rs_ag",
-               "auto": "rs_ag", "bsp": "bsp"}[mode]
+    combine = {"ring": "ring", "rs_ag": "rs_ag", "auto": "rs_ag",
+               "bsp": "bsp"}[mode]
     return fd.decode_attention_fused_sm(
         q, k_new, v_new, k_cache, v_cache, cur_len, ctx.mesh, scale=scale,
         mode=combine, window=window, rolling_len=rolling_len, active=active)
@@ -134,8 +131,8 @@ def decode_attn_paged(q, k_new, v_new, k_pool, v_pool, cur_len,
     Returns (out, k_pool, v_pool)."""
     ctx = dctx.current()
     mode = _mode(ctx)
-    combine = {"ring": "ring", "pallas": "ring", "rs_ag": "rs_ag",
-               "auto": "rs_ag", "bsp": "bsp"}[mode]
+    combine = {"ring": "ring", "rs_ag": "rs_ag", "auto": "rs_ag",
+               "bsp": "bsp"}[mode]
     return fd.decode_paged_attention_fused_sm(
         q, k_new, v_new, k_pool, v_pool, cur_len, block_tables, ctx.mesh,
         scale=scale, mode=combine, window=window, active=active,

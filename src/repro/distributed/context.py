@@ -21,7 +21,7 @@ from repro.distributed.sharding_rules import Rules
 class DistContext:
     mesh: Mesh
     rules: Rules
-    fusion_mode: str = "auto"      # bsp | ring | pallas | auto
+    fusion_mode: str = "auto"      # auto | bsp | ring
 
     @property
     def model_axis_size(self) -> int:
@@ -39,7 +39,8 @@ _CURRENT: DistContext | None = None
 
 
 def single_device_context(fusion_mode: str = "auto") -> DistContext:
-    mesh = Mesh([[jax.devices()[0]]], ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(devices=jax.devices()[:1])
     return DistContext(mesh=mesh, rules=Rules(mesh), fusion_mode=fusion_mode)
 
 

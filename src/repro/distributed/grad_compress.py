@@ -16,8 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import jax_compat
-
 
 def compress_bf16(g):
     return g.astype(jnp.bfloat16)
@@ -54,7 +52,7 @@ def compressed_psum_tree(grads, axis: str, scheme: str = "bf16",
     Call inside shard_map (manual over `axis`). Returns (mean_grads,
     new_residual). With error feedback: residual carries e = g - Q(g).
     """
-    n = jax_compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, r):
         g32 = g.astype(jnp.float32)

@@ -40,6 +40,7 @@ DEFAULT_RULES: dict[str, tuple[str, ...]] = {
     "batch": ("pod", "data"),
     "seq": ("model",),           # sequence parallelism between blocks
     "kv_seq": ("model",),        # decode KV cache sequence sharding
+    "kv_blocks": ("model",),     # paged KV pool block dim (serving)
     "act_embed": (),
     "act_mlp": ("model",),
     "act_heads": ("model",),

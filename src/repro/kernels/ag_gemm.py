@@ -40,30 +40,28 @@ from repro.core import jax_compat
 
 def _ag_gemm_kernel(a_ref, b_ref, o_ref, a_bufs, b_buf, acc_ref,
                     local_sem, send_sem, recv_sem, fetch_sem,
-                    *, axis: str, W: int, nn: int, bn: int,
-                    use_barrier: bool = True):
+                    *, axis: str, W: int, nn: int, bn: int):
     i = lax.axis_index(axis)
     n = pl.program_id(0)          # N tile (major)
     t = pl.program_id(1)          # ring step (minor)
     k = a_ref.shape[-1]
     s = lax.rem(i - t + W, W)     # shard id handled at this ring step
 
-    if use_barrier:
-        @pl.when((n == 0) & (t == 0) & (W > 1))
-        def _barrier():
-            # Neighbourhood barrier: nobody pushes into our inbox before
-            # we are inside the kernel (symmetric-heap readiness
-            # handshake).
-            barrier = pltpu.get_barrier_semaphore()
-            right = lax.rem(i + 1, W)
-            left = lax.rem(i - 1 + W, W)
-            pltpu.semaphore_signal(
-                barrier, inc=1, device_id=jax_compat.pallas_device_id(right),
-                device_id_type=pltpu.DeviceIdType.MESH)
-            pltpu.semaphore_signal(
-                barrier, inc=1, device_id=jax_compat.pallas_device_id(left),
-                device_id_type=pltpu.DeviceIdType.MESH)
-            pltpu.semaphore_wait(barrier, 2)
+    @pl.when((n == 0) & (t == 0) & (W > 1))
+    def _barrier():
+        # Neighbourhood barrier: nobody pushes into our inbox before
+        # we are inside the kernel (symmetric-heap readiness
+        # handshake).
+        barrier = pltpu.get_barrier_semaphore()
+        right = lax.rem(i + 1, W)
+        left = lax.rem(i - 1 + W, W)
+        pltpu.semaphore_signal(
+            barrier, inc=1, device_id=(right,),
+            device_id_type=pltpu.DeviceIdType.MESH)
+        pltpu.semaphore_signal(
+            barrier, inc=1, device_id=(left,),
+            device_id_type=pltpu.DeviceIdType.MESH)
+        pltpu.semaphore_wait(barrier, 2)
 
     @pl.when((n == 0) & (t == 0))
     def _load_own():
@@ -76,7 +74,7 @@ def _ag_gemm_kernel(a_ref, b_ref, o_ref, a_bufs, b_buf, acc_ref,
         src_ref=a_bufs.at[s],
         dst_ref=a_bufs.at[s],
         send_sem=send_sem, recv_sem=recv_sem,
-        device_id=jax_compat.pallas_device_id(lax.rem(i + 1, W)),
+        device_id=(lax.rem(i + 1, W),),
         device_id_type=pltpu.DeviceIdType.MESH,
     )
 
@@ -144,8 +142,7 @@ def ag_gemm_fused(a_shard, b_full, *, axis: str, bn: int = 256,
 
     return pl.pallas_call(
         functools.partial(
-            _ag_gemm_kernel, axis=axis, W=W, nn=nn, bn=bn,
-            use_barrier=jax_compat.pallas_barrier_supported(interpret)),
+            _ag_gemm_kernel, axis=axis, W=W, nn=nn, bn=bn),
         grid=(nn, W),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.ANY),   # a_shard (HBM)
@@ -163,7 +160,7 @@ def ag_gemm_fused(a_shard, b_full, *, axis: str, bn: int = 256,
             pltpu.SemaphoreType.DMA,                # B fetch
         ],
         interpret=jax_compat.pallas_interpret(interpret),
-        compiler_params=jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id,
             dimension_semantics=("arbitrary", "arbitrary")),
     )(a_shard, b_full)

@@ -41,8 +41,7 @@ NEG = float(jnp.finfo(jnp.float32).min)
 def _fd_kernel(len_ref, q_ref, k_ref, v_ref, out_ref,
                inbox, kbuf, vbuf, part, fetch_sem, send_sem, recv_sems,
                local_sem,
-               *, axis: str, W: int, blk: int, scale: float,
-               use_barrier: bool = True):
+               *, axis: str, W: int, blk: int, scale: float):
     i = lax.axis_index(axis)
     B, H, D = q_ref.shape
     S_loc, KVH = k_ref.shape[1], k_ref.shape[2]
@@ -50,18 +49,16 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, out_ref,
     nblk = S_loc // blk
     cur_len = len_ref[0]
 
-    if use_barrier:
-        @pl.when(W > 1)
-        def _barrier():
-            barrier = pltpu.get_barrier_semaphore()
-            for d in range(W):
-                if d != 0:
-                    pltpu.semaphore_signal(
-                        barrier, inc=1,
-                        device_id=jax_compat.pallas_device_id(
-                            lax.rem(i + d, W)),
-                        device_id_type=pltpu.DeviceIdType.MESH)
-            pltpu.semaphore_wait(barrier, W - 1)
+    @pl.when(W > 1)
+    def _barrier():
+        barrier = pltpu.get_barrier_semaphore()
+        for d in range(W):
+            if d != 0:
+                pltpu.semaphore_signal(
+                    barrier, inc=1,
+                    device_id=(lax.rem(i + d, W),),
+                    device_id_type=pltpu.DeviceIdType.MESH)
+        pltpu.semaphore_wait(barrier, W - 1)
 
     # ---------------- Part 1: local attention with online softmax ----------
     for b in range(B):
@@ -108,7 +105,7 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, out_ref,
             push = pltpu.make_async_remote_copy(
                 src_ref=part, dst_ref=inbox.at[i],
                 send_sem=send_sem, recv_sem=recv_sems.at[i],
-                device_id=jax_compat.pallas_device_id(dst),
+                device_id=(dst,),
                 device_id_type=pltpu.DeviceIdType.MESH)
             push.start()
             push.wait_send()
@@ -146,8 +143,7 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, out_ref,
 def _fd_paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, out_ref,
                      inbox, kbuf, vbuf, part, fetch_sem, send_sem, recv_sems,
                      local_sem,
-                     *, axis: str, W: int, scale: float,
-                     use_barrier: bool = True):
+                     *, axis: str, W: int, scale: float):
     """Paged variant of ``_fd_kernel`` with BOUNDED per-slot work: the
     local KV shard is a slice of the BLOCK POOL — (n_loc, block_size,
     KVH, D), global block ``i*n_loc + j`` at local index j — and the
@@ -172,18 +168,16 @@ def _fd_paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, out_ref,
     g = H // KVH
     base = i * n_loc
 
-    if use_barrier:
-        @pl.when(W > 1)
-        def _barrier():
-            barrier = pltpu.get_barrier_semaphore()
-            for d in range(W):
-                if d != 0:
-                    pltpu.semaphore_signal(
-                        barrier, inc=1,
-                        device_id=jax_compat.pallas_device_id(
-                            lax.rem(i + d, W)),
-                        device_id_type=pltpu.DeviceIdType.MESH)
-            pltpu.semaphore_wait(barrier, W - 1)
+    @pl.when(W > 1)
+    def _barrier():
+        barrier = pltpu.get_barrier_semaphore()
+        for d in range(W):
+            if d != 0:
+                pltpu.semaphore_signal(
+                    barrier, inc=1,
+                    device_id=(lax.rem(i + d, W),),
+                    device_id_type=pltpu.DeviceIdType.MESH)
+        pltpu.semaphore_wait(barrier, W - 1)
 
     # -------- Part 1: table-driven bounded local attention -----------------
     for b in range(B):
@@ -237,7 +231,7 @@ def _fd_paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, out_ref,
             push = pltpu.make_async_remote_copy(
                 src_ref=part, dst_ref=inbox.at[i],
                 send_sem=send_sem, recv_sem=recv_sems.at[i],
-                device_id=jax_compat.pallas_device_id(dst),
+                device_id=(dst,),
                 device_id_type=pltpu.DeviceIdType.MESH)
             push.start()
             push.wait_send()
@@ -308,12 +302,11 @@ def flash_decode_paged_fused(q, k_pool, v_pool, cur_len, tables, *,
     )
     return pl.pallas_call(
         functools.partial(
-            _fd_paged_kernel, axis=axis, W=W, scale=scale,
-            use_barrier=jax_compat.pallas_barrier_supported(interpret)),
+            _fd_paged_kernel, axis=axis, W=W, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=jax_compat.pallas_interpret(interpret),
-        compiler_params=jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
     )(cur_len, tables, q, k_pool, v_pool)
 
@@ -355,11 +348,10 @@ def flash_decode_fused(q, k_shard, v_shard, cur_len, *, axis: str, W: int,
     )
     return pl.pallas_call(
         functools.partial(
-            _fd_kernel, axis=axis, W=W, blk=blk, scale=scale,
-            use_barrier=jax_compat.pallas_barrier_supported(interpret)),
+            _fd_kernel, axis=axis, W=W, blk=blk, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=jax_compat.pallas_interpret(interpret),
-        compiler_params=jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
     )(cur_len, q, k_shard, v_shard)

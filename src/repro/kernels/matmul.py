@@ -54,6 +54,6 @@ def matmul(a, b, *, bm: int = 256, bk: int = 512, bn: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=jax_compat.pallas_interpret(interpret),
-        compiler_params=jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, b)

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collective_matmul as cm
-from repro.core import jax_compat
 from repro.core import taxes
 from repro.kernels import ag_gemm as _ag
 from repro.kernels import flash_decode as _fd
@@ -45,10 +44,10 @@ def ag_gemm(a, b, mesh, *, axis: str = "model", bn: int = 256,
                                        mode="ring_bidir" if W > 1 else "bsp")
 
     fn = functools.partial(_ag.ag_gemm_fused, axis=axis, bn=bn)
-    return jax_compat.shard_map(fn, mesh=mesh,
-                                in_specs=(P(None, axis), P()),
-                                out_specs=P(), axis_names={axis},
-                                check_vma=False)(a, b)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(P(None, axis), P()),
+                         out_specs=P(), axis_names={axis},
+                         check_vma=False)(a, b)
 
 
 def flash_decode(q, k_cache, v_cache, cur_len, mesh, *, axis: str = "model",
@@ -60,8 +59,8 @@ def flash_decode(q, k_cache, v_cache, cur_len, mesh, *, axis: str = "model",
     fn = functools.partial(_fd.flash_decode_fused, axis=axis, W=W, blk=blk,
                            scale=scale)
     ins = (P(), P(None, axis, None, None), P(None, axis, None, None), P())
-    return jax_compat.shard_map(fn, mesh=mesh, in_specs=ins, out_specs=P(),
-                                axis_names={axis}, check_vma=False)(
+    return jax.shard_map(fn, mesh=mesh, in_specs=ins, out_specs=P(),
+                         axis_names={axis}, check_vma=False)(
         q, k_cache, v_cache, cl)
 
 
@@ -82,6 +81,6 @@ def flash_decode_paged(q, k_pool, v_pool, cur_len, tables, mesh, *,
                            scale=scale)
     ins = (P(), P(axis, None, None, None), P(axis, None, None, None),
            P(), P())
-    return jax_compat.shard_map(fn, mesh=mesh, in_specs=ins, out_specs=P(),
-                                axis_names={axis}, check_vma=False)(
+    return jax.shard_map(fn, mesh=mesh, in_specs=ins, out_specs=P(),
+                         axis_names={axis}, check_vma=False)(
         q, k_pool, v_pool, cl, tb)

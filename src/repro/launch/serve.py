@@ -16,6 +16,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import get_config, smoke_config
 from repro.distributed import context as dctx
 from repro.distributed.sharding_rules import rules_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 from repro.serving.engine import Engine, Request
@@ -23,6 +24,7 @@ from repro.serving.metrics import percentile
 
 
 def main(argv=None):
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true")
@@ -52,7 +54,7 @@ def main(argv=None):
     p.add_argument("--requests", type=int, default=8)
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--fusion-mode", default="auto",
-                   choices=("auto", "bsp", "ring", "pallas"))
+                   choices=("auto", "bsp", "ring"))
     p.add_argument("--sampler", default="greedy",
                    choices=("greedy", "temperature"))
     p.add_argument("--scheduler", default="fcfs",
@@ -89,6 +91,8 @@ def main(argv=None):
         cfg = smoke_config(cfg)
     if not cfg.has_decode:
         raise SystemExit(f"{cfg.name} is encoder-only: no decode serving")
+    # a server keeps no master weights: params live in the compute dtype
+    cfg = cfg.replace(param_dtype=cfg.dtype)
 
     n = len(jax.devices())
     model = min(args.tp, n)
@@ -97,11 +101,17 @@ def main(argv=None):
                             rules=rules_for(cfg, mesh))
 
     with dctx.use(ctx), mesh:
-        params = lm.init_params(jax.random.PRNGKey(args.seed), cfg)
+        params = lm.init_params_sharded(jax.random.PRNGKey(args.seed), cfg,
+                                        ctx.rules)
         if args.ckpt_dir:
             ck = Checkpointer(args.ckpt_dir)
-            tree, manifest = ck.restore(None, {"params": params})
-            params = tree["params"]
+            tree, manifest = ck.restore(
+                None, {"params": params},
+                shardings={"params": jax.tree.map(lambda x: x.sharding,
+                                                  params)})
+            # training checkpoints hold float32 masters
+            params = jax.tree.map(lambda t, x: x.astype(t.dtype), params,
+                                  tree["params"])
             print(f"[serve] restored step {manifest['step']}")
 
         eng = Engine(params, cfg, batch=args.batch, max_len=args.max_len,

@@ -693,10 +693,14 @@ async def _send_json(writer, status: int, obj: dict,
 
 
 # --------------------------------------------------------------------- CLI
-def build_engine(args):
-    """Model + mesh + Engine from CLI args (shared with serve.py's
-    conventions; import-time jax stays out of module scope so taxlint
-    and --help never pay for it)."""
+def build_engine(args, devices=None, params=None):
+    """Model + mesh + Engine from CLI args, over ``devices`` (default:
+    every local device). Shared with serve.py's conventions; import-time
+    jax stays out of module scope so taxlint and --help never pay for
+    it. Params are held in the compute dtype (a server keeps no master
+    weights) and are born sharded by the mesh's rules; ``params`` (built
+    by an earlier call with the same args) gives a fresh engine over the
+    same weights without initialising them again."""
     import jax
 
     from repro.configs import get_config, smoke_config
@@ -711,15 +715,16 @@ def build_engine(args):
         cfg = smoke_config(cfg)
     if not cfg.has_decode:
         raise SystemExit(f"{cfg.name} is encoder-only: no decode serving")
-    n = len(jax.devices())
+    cfg = cfg.replace(param_dtype=cfg.dtype)
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
     model = min(args.tp, n)
-    mesh = make_host_mesh(data=n // model, model=model)
+    mesh = make_host_mesh(data=n // model, model=model, devices=devices)
     ctx = dctx.make_context(mesh, fusion_mode=args.fusion_mode,
                             rules=rules_for(cfg, mesh))
-    stack = dctx.use(ctx)
-    stack.__enter__()
-    mesh.__enter__()
-    params = lm.init_params(jax.random.PRNGKey(args.seed), cfg)
+    if params is None:
+        params = lm.init_params_sharded(jax.random.PRNGKey(args.seed), cfg,
+                                        ctx.rules)
     fault_plan = None
     if getattr(args, "chaos_plan", None):
         from repro.serving.faults import FaultPlan
@@ -730,15 +735,17 @@ def build_engine(args):
         fault_plan = FaultPlan.seeded(args.chaos_seed,
                                       args.chaos_ticks,
                                       batch=args.batch)
-    engine = Engine(params, cfg, batch=args.batch, max_len=args.max_len,
-                    prefill_chunk=args.prefill_chunk, sampler=args.sampler,
-                    seed=args.seed, block_size=args.block_size,
-                    n_blocks=args.kv_blocks, scheduler=args.scheduler,
-                    decode_steps=args.decode_steps,
-                    megatick_token_budget=args.megatick_token_budget,
-                    bounded_gather=args.paged_gather == "bounded",
-                    fault_plan=fault_plan,
-                    degraded=bool(getattr(args, "degraded", False)))
+    with dctx.use(ctx):      # the engine keeps the context it is built in
+        engine = Engine(params, cfg, batch=args.batch, max_len=args.max_len,
+                        prefill_chunk=args.prefill_chunk,
+                        sampler=args.sampler, seed=args.seed,
+                        block_size=args.block_size, n_blocks=args.kv_blocks,
+                        scheduler=args.scheduler,
+                        decode_steps=args.decode_steps,
+                        megatick_token_budget=args.megatick_token_budget,
+                        bounded_gather=args.paged_gather == "bounded",
+                        fault_plan=fault_plan,
+                        degraded=bool(getattr(args, "degraded", False)))
     if getattr(args, "resume", False) and args.checkpoint_dir:
         from repro.checkpoint.checkpointer import Checkpointer
         ckpt = Checkpointer(args.checkpoint_dir)
@@ -784,7 +791,7 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--paged-gather", default="bounded",
                    choices=("bounded", "masked"))
     p.add_argument("--fusion-mode", default="auto",
-                   choices=("auto", "bsp", "ring", "pallas"))
+                   choices=("auto", "bsp", "ring"))
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--seed", type=int, default=0)
     # ------------------------------------------------------- robustness
@@ -816,6 +823,8 @@ def make_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = make_parser().parse_args(argv)
     engine = build_engine(args)
     from repro.distributed.fault_tolerance import PreemptionGuard

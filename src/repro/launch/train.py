@@ -26,6 +26,7 @@ from repro.distributed.fault_tolerance import (Heartbeat, PreemptionGuard,
                                                StragglerWatchdog)
 from repro.distributed.sharding_rules import rules_for
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import lm
 from repro.optim import adamw, schedule
@@ -40,6 +41,7 @@ def build_mesh(args):
 
 
 def main(argv=None):
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true",
@@ -54,7 +56,7 @@ def main(argv=None):
     p.add_argument("--multi-pod", action="store_true")
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--fusion-mode", default="auto",
-                   choices=("auto", "bsp", "ring", "pallas"))
+                   choices=("auto", "bsp", "ring"))
     p.add_argument("--ckpt-dir", default=None)
     p.add_argument("--ckpt-every", type=int, default=100)
     p.add_argument("--resume", action="store_true")
@@ -83,9 +85,8 @@ def main(argv=None):
 
     with dctx.use(ctx), mesh:
         psh = steps_lib.param_shardings(cfg, rules)
-        params = jax.jit(
-            lambda k: lm.init_params(k, cfg), out_shardings=psh)(
-            jax.random.PRNGKey(args.seed))
+        params = lm.init_params_sharded(jax.random.PRNGKey(args.seed), cfg,
+                                        rules)
         osh = steps_lib.opt_state_shardings(cfg, rules, psh)
         opt_state = jax.jit(adamw.init_state, out_shardings=osh)(params)
 

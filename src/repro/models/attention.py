@@ -261,7 +261,7 @@ def decode_attn_step(params, x, cache, cur_len, cfg, active=None,
     # `window` tokens, and softmax permutation-invariance keeps it exact.
     rolling = (cfg.sliding_window is not None
                and S_max <= cfg.sliding_window)
-    if W > 1 and ctx.fusion_mode in ("ring", "pallas", "rs_ag"):
+    if W > 1 and ctx.fusion_mode in ("ring", "rs_ag"):
         # fused ownership-aware path: update+attend+combine in one
         # shard_map region (no XLA scatter collectives)
         o, ck, cv = patterns.decode_attn_fused(

@@ -10,6 +10,7 @@ Handles all assigned families:
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any
 
 import jax
@@ -20,7 +21,8 @@ from repro.distributed.sharding_rules import constrain
 from repro.models import transformer
 from repro.models.layers import (apply_embed, apply_norm, apply_unembed,
                                  embed_spec, norm_spec)
-from repro.models.module import Param, axes_tree, init_tree
+from repro.models.module import (Param, axes_tree, init_tree, is_param,
+                                 shapes_tree)
 
 
 def lm_spec(cfg):
@@ -39,11 +41,26 @@ def lm_spec(cfg):
                             axes=(None, "embed"))}
     if cfg.block == "rwkv":
         spec["ln_in"] = norm_spec(cfg.d_model, "layernorm")
-    return spec
+    # every leaf is stored in cfg.param_dtype: float32 master weights for
+    # training, cfg.dtype for a server, which keeps no master copy
+    return jax.tree.map(
+        lambda p: dataclasses.replace(p, dtype=cfg.param_dtype), spec,
+        is_leaf=is_param)
 
 
 def init_params(key, cfg):
     return init_tree(key, lm_spec(cfg))
+
+
+def init_params_sharded(key, cfg, rules):
+    """``init_params`` under ``jax.jit`` with each leaf's rules sharding
+    as its output sharding: leaves are born sharded on the rules' mesh
+    in ``cfg.param_dtype``, so no whole float32 tree is ever built and
+    nothing lands on the first device by default."""
+    spec = lm_spec(cfg)
+    shardings = rules.shardings(axes_tree(spec), shapes_tree(spec))
+    return jax.jit(lambda k: init_tree(k, spec),
+                   out_shardings=shardings)(key)
 
 
 def param_axes(cfg):
@@ -156,6 +173,33 @@ def init_paged_decode_state(params, cfg, batch: int, n_blocks: int,
                                                     block_size, cfg.dtype),
             "cur_len": jnp.zeros((batch,), jnp.int32),
             "block_tables": jnp.full((batch, max_blocks), -1, jnp.int32)}
+
+
+def paged_state_axes(state):
+    """Logical axes of a paged decode state (arrays or shapes): the
+    layer-stacked KV pool leaves (layers, n_blocks, block_size, KVH, hd)
+    shard their block dim on ``kv_blocks``, the layout the paged
+    attention regions expect; positions, tables and recurrent state are
+    replicated."""
+    def axes(path, x):
+        if str(getattr(path[-1], "key", "")) in ("k", "v") and x.ndim == 5:
+            return ("layers", "kv_blocks", None, None, None)
+        return (None,) * x.ndim
+    return jax.tree_util.tree_map_with_path(axes, state)
+
+
+def init_paged_decode_state_sharded(cfg, batch: int, n_blocks: int,
+                                    block_size: int, max_blocks: int,
+                                    rules):
+    """``init_paged_decode_state`` under ``jax.jit``, each leaf born with
+    its rules sharding (:func:`paged_state_axes`)."""
+    def init():
+        return init_paged_decode_state(None, cfg, batch, n_blocks,
+                                       block_size, max_blocks)
+
+    shapes = jax.eval_shape(init)
+    shardings = rules.shardings(paged_state_axes(shapes), shapes)
+    return jax.jit(init, out_shardings=shardings)()
 
 
 def set_slot_len(state, slot: int, n: int):

@@ -3,7 +3,7 @@
 The decode step is where the paper's Flash Decode lives: one jitted
 step runs the whole active batch against the block-sharded paged KV
 pool, with the partial-softmax combine executed by the configured
-fusion mode (bsp / ring / pallas).
+fusion mode (auto / bsp / ring).
 
 This is TRUE per-slot continuous batching over PAGED KV: the jitted
 state carries a (B,) position vector and a (B, max_blocks) block table
@@ -193,6 +193,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed import context as dctx
 from repro.distributed.fault_tolerance import StragglerWatchdog
 from repro.models import lm
 from repro.serving import sampler as sampler_lib
@@ -336,6 +337,10 @@ class Engine:
                 f"at least cover a full decode megatick, or the 1/K "
                 f"dispatch bound cannot hold")
         self.policy = get_scheduler(scheduler)   # fail fast, pre-pool-init
+        # the DistContext (mesh, rules, fusion mode) current at
+        # construction: the pool is laid out by it, and every program
+        # the engine builds is traced under it, whatever is current then
+        self.ctx = dctx.current()
         self.params = params
         self.cfg = cfg
         self.batch = batch
@@ -402,19 +407,27 @@ class Engine:
         compiled programs can no longer see."""
         cfg = self.cfg
         sampler = self.sampler
+        ctx = self.ctx
+
+        def under_ctx(fn):
+            def traced(*args):
+                with dctx.use(ctx):
+                    return fn(*args)
+            return traced
+
         # two jitted paths sharing the pool state: a 1-token step for
         # all-decoding ticks, a C-token scan when any slot is prefilling.
         # gw is the STATIC gather width (power-of-two bucket of the
         # pool's live max_blocks_in_use watermark): each distinct bucket
         # is one extra specialization, log2(max_blocks) worst case.
         bounded = self.bounded_gather
-        self._step1 = jax.jit(
+        self._step1 = jax.jit(under_ctx(
             lambda p, t, a, s, gw: lm.decode_step(
-                p, t, s, cfg, active=a, gather_width=gw, bounded=bounded),
+                p, t, s, cfg, active=a, gather_width=gw, bounded=bounded)),
             static_argnums=(4,))
-        self._stepC = jax.jit(
+        self._stepC = jax.jit(under_ctx(
             lambda p, t, c, s, gw: lm.decode_chunk(
-                p, t, c, s, cfg, gather_width=gw, bounded=bounded),
+                p, t, c, s, cfg, gather_width=gw, bounded=bounded)),
             static_argnums=(4,))
         # the K-step decode megatick: sampling runs INSIDE the scan
         # (greedy argmax, or the seeded batch sampler whose keys fold
@@ -436,7 +449,8 @@ class Engine:
                                    sample_fn=sample_fn, gather_width=gw,
                                    bounded=bounded)
 
-        self._stepK = jax.jit(_megatick_fn, static_argnums=(8, 9))
+        self._stepK = jax.jit(under_ctx(_megatick_fn),
+                              static_argnums=(8, 9))
 
         # the mixed prefill+decode megatick: one fused program in which
         # each slot consumes its next prompt-chunk tokens and/or runs
@@ -458,7 +472,8 @@ class Engine:
                                    steps=S, sample_fn=sample_fn,
                                    gather_width=gw, bounded=bounded)
 
-        self._stepM = jax.jit(_mixedtick_fn, static_argnums=(11, 12))
+        self._stepM = jax.jit(under_ctx(_mixedtick_fn),
+                              static_argnums=(11, 12))
         self._sample = jax.jit(sampler_lib.sample_batch)
         self._greedy = jax.jit(sampler_lib.greedy)
 

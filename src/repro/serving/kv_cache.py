@@ -138,20 +138,21 @@ class CachePool:
 
     def __post_init__(self):
         from repro.distributed import context as dctx
+        ctx = dctx.current()
         bs = self.block_size
         self.max_blocks = blocks_for(self.max_len, bs)
         if self.n_blocks is None:
             self.n_blocks = self.batch * self.max_blocks
-        W = dctx.current().model_axis_size
+        W = ctx.model_axis_size
         self.n_blocks += (-self.n_blocks) % max(W, 1)
         # rwkv has no KV cache: the block pool is bookkeeping-only there
         self._needs_blocks = self.cfg.block != "rwkv"
         # prefix reuse seeds KV blocks only; recurrent state (mamba) can't
         # be rebuilt from them, so hybrids prefill from scratch
         self._can_share = self.cfg.block in ("attn_mlp", "attn_moe")
-        self.state = lm.init_paged_decode_state(
-            self.params, self.cfg, self.batch, self.n_blocks, bs,
-            self.max_blocks)
+        self.state = lm.init_paged_decode_state_sharded(
+            self.cfg, self.batch, self.n_blocks, bs, self.max_blocks,
+            ctx.rules)
         # host mirrors: scheduler reads/updates these synchronously; the
         # device cur_len advances inside the jitted step and block_tables
         # re-upload via sync() when dirty

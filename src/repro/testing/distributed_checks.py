@@ -15,13 +15,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import jax_compat
+from repro.launch.mesh import make_mesh
 
 ATOL, RTOL = 2e-4, 2e-4
 
 
 def _mesh(data=2, model=4):
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def _rand(key, shape, dtype=jnp.float32):
@@ -73,9 +73,9 @@ def check_all_gather_ring():
     mesh = _mesh(1, 8)
     x = _rand(0, (8, 4, 128))
     fn = functools.partial(cm.all_gather_ring, axis="model", gather_axis=0)
-    got = jax.jit(jax_compat.shard_map(fn, mesh=mesh, in_specs=P("model"),
-                                       out_specs=P(), axis_names={"model"},
-                                       check_vma=False))(x)
+    got = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("model"),
+                                out_specs=P(), axis_names={"model"},
+                                check_vma=False))(x)
     np.testing.assert_allclose(got, x, rtol=0, atol=0)
 
 
@@ -123,7 +123,7 @@ def check_flash_decode_window():
 # ------------------------------------------------------------ pallas kernels
 def check_pallas_ag_gemm():
     from repro.kernels import ops
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     M, K, N = 64, 256, 512
     a, b = _rand(0, (M, K)), _rand(1, (K, N))
     a_sh = jax.device_put(a, NamedSharding(mesh, P(None, "model")))
@@ -133,7 +133,7 @@ def check_pallas_ag_gemm():
 
 def check_pallas_ag_gemm_dtypes():
     from repro.kernels import ops
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     for dt, tol in ((jnp.float32, 1e-4), (jnp.bfloat16, 2e-2)):
         a = _rand(0, (32, 128)).astype(dt)
         b = _rand(1, (128, 256)).astype(dt)
@@ -146,7 +146,7 @@ def check_pallas_ag_gemm_dtypes():
 
 def check_pallas_flash_decode():
     from repro.kernels import ops, ref
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     B, H, KVH, D, S, W = 2, 8, 4, 32, 64, 4
     q, k, v = _rand(0, (B, H, D)), _rand(1, (B, S, KVH, D)), _rand(2, (B, S, KVH, D))
     cur = 41
@@ -231,7 +231,7 @@ def check_grad_compress_psum():
             mean, res = gc.compressed_psum_tree(gg, "data", scheme=scheme)
             return mean
         specs = {k: P() for k in g}
-        got = jax.jit(jax_compat.shard_map(
+        got = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(specs,), out_specs=specs,
             axis_names={"data"}, check_vma=False))(g)
         tol = {"bf16": 1e-2, "int8": 3e-2, "none": 1e-6}[scheme]
@@ -366,7 +366,7 @@ def check_engine_staggered_admission():
 def check_collective_matmul_validation():
     """The silent-wrong-result shapes must now raise loud ValueErrors."""
     from repro.core import collective_matmul as cm
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
 
     def expect_raises(fn, frag):
         try:
@@ -396,7 +396,7 @@ def check_pallas_ag_gemm_bn_clamp():
     """ag_gemm_fused with N not a multiple of bn: bn must clamp to a
     divisor of N instead of crashing (the old `assert N % bn == 0`)."""
     from repro.kernels import ops
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     M, K, N = 32, 256, 384       # N=384 not a multiple of bn=256
     a, b = _rand(0, (M, K)), _rand(1, (K, N))
     a_sh = jax.device_put(a, NamedSharding(mesh, P(None, "model")))
@@ -449,7 +449,7 @@ def check_pallas_flash_decode_paged():
     """Fused Pallas kernel with block-table translation == paged oracle."""
     from repro.core import flash_decode as fd
     from repro.kernels import ops
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = make_mesh((4,), ("model",))
     B, H, KVH, D = 2, 8, 4, 32
     bs, n_blocks = 8, 16
     q = _rand(0, (B, H, D))

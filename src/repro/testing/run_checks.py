@@ -8,6 +8,7 @@ means one crash can't take down the whole battery.
 
 Prints one JSON object mapping check name -> {ok, error}.
 """
+import concurrent.futures
 import json
 import os
 import subprocess
@@ -20,6 +21,7 @@ def run_one(name: str) -> dict:
             f"from repro.testing import distributed_checks as dc; "
             f"dc.{name}()")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("XLA_FLAGS",
                    "--xla_force_host_platform_device_count=8")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
@@ -31,18 +33,24 @@ def run_one(name: str) -> dict:
                       f"exit code {proc.returncode}")}
 
 
+def _run_contained(name: str) -> dict:
+    try:
+        return run_one(name)
+    except Exception:
+        return {"ok": False, "error": traceback.format_exc()[-3000:]}
+
+
 def main():
     from repro.testing import distributed_checks as dc
+    names = [fn.__name__ for fn in dc.ALL_CHECKS]
+    # a few check processes at a time: one after another, the battery
+    # alone would take most of the test suite's time limit
+    workers = max(1, min(4, (os.cpu_count() or 2) // 2))
     results = {}
-    for fn in dc.ALL_CHECKS:
-        name = fn.__name__
-        try:
-            results[name] = run_one(name)
-        except Exception:
-            results[name] = {"ok": False,
-                             "error": traceback.format_exc()[-3000:]}
-        print(f"# {name}: {'OK' if results[name]['ok'] else 'FAIL'}",
-              flush=True)
+    with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+        for name, res in zip(names, pool.map(_run_contained, names)):
+            results[name] = res
+            print(f"# {name}: {'OK' if res['ok'] else 'FAIL'}", flush=True)
     print(json.dumps(results))
 
 

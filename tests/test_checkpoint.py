@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.launch.mesh import make_mesh
 
 
 def _tree(seed=0):
@@ -74,7 +75,7 @@ def test_elastic_restore_resharding(tmp_path):
     """Restore with explicit shardings device_puts onto the current mesh
     (single device here, but exercises the code path)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ck = Checkpointer(str(tmp_path), async_save=False)
     t = _tree(2)
     ck.save(1, t)

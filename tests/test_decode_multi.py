@@ -194,6 +194,7 @@ def test_promoted_megatick_bsp_check_8_devices():
     the full mode x sampler x window matrix
     (``check_engine_megatick_token_identity``)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     code = ("from repro.testing import distributed_checks as dc; "

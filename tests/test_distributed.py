@@ -23,6 +23,7 @@ CHECK_NAMES = [f.__name__ for f in dc.ALL_CHECKS]
 @pytest.fixture(scope="session")
 def battery_results():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run(

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs import get_config, smoke_config
 from repro.core import flash_decode as fd
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.serving.engine import Engine, Request
 from repro.serving.kv_cache import CachePool, pow2_bucket
@@ -42,7 +43,7 @@ def _rand(key, shape):
 
 def _run_fused_1dev(q, k_new, v_new, k_pool, v_pool, cur, tables, *,
                     window=None, bounded=True):
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     return jax.jit(
         lambda q, kn, vn, kp, vp, c, t:
         fd.decode_paged_attention_fused_sm(
@@ -233,6 +234,7 @@ def test_promoted_bounded_bsp_check_8_devices():
     battery runs the full mode matrix, this keeps the bounded fused
     region from regressing silently between nightlies."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     code = ("from repro.testing import distributed_checks as dc; "
