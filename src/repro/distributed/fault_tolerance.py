@@ -115,7 +115,7 @@ class StragglerWatchdog:
     time.  Callers should feed it MONOTONIC-clock durations
     (``time.monotonic`` deltas): serving megaticks are milliseconds,
     where a wall-clock NTP slew is indistinguishable from a straggler.
-    ``timed()`` wraps that idiom."""
+    The serving engine feeds it each tick's ``engine.tick`` span."""
     factor: float = 2.0
     window: int = 50
     min_samples: int = 10
@@ -133,11 +133,6 @@ class StragglerWatchdog:
         if slow:
             self.slow_steps.append((step, dt, med))
         return slow
-
-    def timed(self, step: int, t0: float) -> bool:
-        """Record the monotonic elapsed time since ``t0`` for ``step``
-        (``t0`` from ``time.monotonic()``); returns straggler-ness."""
-        return self.record(step, time.monotonic() - t0)
 
     def summary(self) -> dict:
         return {"n_slow": len(self.slow_steps),

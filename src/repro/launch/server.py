@@ -45,9 +45,12 @@ Endpoints:
   blocks — immediately re-allocatable — while registered prefix
   chunks stay LRU-resident. Closing the SSE socket mid-stream (a user
   hang-up) cancels the same way.
-* ``GET /v1/metrics`` — the engine metrics snapshot taken at the last
+* ``GET /v1/metrics`` — the engine's counters as snapshot at the last
   megatick boundary (never syncs the device), merged with the server's
-  own robustness counters (``server_tick_failures``, ``draining``).
+  own counters (``server_tick_failures``, ``draining``, the drive
+  loop's span seconds and ``server_gap_s``/``server_gaps``); the
+  TTFT/TPOT summaries over every finished request are computed when
+  the endpoint is served, never per tick.
 * ``GET /healthz`` — liveness + queue depth (always 200 while the
   process is up — liveness is not readiness).
 * ``GET /readyz`` — readiness: 200 while accepting work, 503 once the
@@ -101,6 +104,8 @@ import json
 import time
 from collections import deque
 
+from repro.serving.metrics import Spans, latency_metrics, request_latency
+
 SSE_HEADERS = (b"HTTP/1.1 200 OK\r\n"
                b"Content-Type: text/event-stream\r\n"
                b"Cache-Control: no-cache\r\n"
@@ -110,6 +115,9 @@ FINISH_LENGTH = "length"          # max_new_tokens reached / cache full
 FINISH_CANCELLED = "cancelled"    # user hung up or DELETE'd the stream
 FINISH_TIMEOUT = "timeout"        # server-side request timeout fired
 FINISH_ERROR = "error"            # engine retired the slot (see faults)
+
+# the drive loop's host spans: intake before a tick, flush after it
+SERVER_SPANS = ("server.intake", "server.flush")
 
 
 @dataclasses.dataclass
@@ -162,7 +170,8 @@ class Server:
         self._intake: deque = deque()       # handles awaiting submit
         self._cancels: deque = deque()      # (rid, finish_reason)
         self._inflight: dict[int, StreamHandle] = {}
-        self._done: list = []               # finished (not aborted) reqs
+        self._done: list = []               # (ttft, tpot) of each finished
+        self._done_tokens = 0               # their output tokens
         # a restored engine already holds requests with live rids: new
         # accepts must not collide with resumed streams
         self._next_rid = max((r.rid for r in engine.queue), default=-1) + 1
@@ -174,6 +183,14 @@ class Server:
         self._drain_deadline = 0.0
         self._drained_step: int | None = None
         self.tick_failures = 0              # megaticks that raised
+        # tracing: the drive loop's spans (server_<name>_s), and the
+        # host time between back-to-back ticks — from the end of one
+        # Engine.tick on the executor thread to the start of the next
+        # while the loop never idled: the server's share of device idle
+        self.spans = Spans(*SERVER_SPANS)
+        self.gap_s = 0.0
+        self.gaps = 0
+        self._tick_end: float | None = None
         self._server: asyncio.AbstractServer | None = None
         self._drive_task: asyncio.Task | None = None
         # ONE worker: the engine is single-owner — the executor thread
@@ -222,7 +239,7 @@ class Server:
             if busy:
                 try:
                     finished = await loop.run_in_executor(
-                        self._exec, self.engine.tick)
+                        self._exec, self._run_tick)
                 except Exception as e:      # noqa: BLE001 — containment
                     # a tick that raises (DispatchFailedError past the
                     # retry budget, an injected hard fault) fails the
@@ -232,6 +249,7 @@ class Server:
                     continue
                 self._flush(finished)
             else:
+                self._tick_end = None       # idle: the next tick has no gap
                 self._wake.clear()
                 if not (self._intake or self._cancels):
                     try:
@@ -240,25 +258,58 @@ class Server:
                     except asyncio.TimeoutError:
                         pass
 
+    def _run_tick(self):
+        """``Engine.tick`` on the executor thread, counting the gap
+        since the previous tick ended when the loop never idled."""
+        start = time.monotonic()
+        if self._tick_end is not None:
+            self.gap_s += start - self._tick_end
+            self.gaps += 1
+        try:
+            return self.engine.tick()
+        finally:
+            self._tick_end = time.monotonic()
+
     def _drive_once_host(self) -> bool:
         """The host-side half of one drive iteration, BETWEEN jitted
         dispatches: submit accepted requests, apply cancellations and
         expired timeouts, refresh the queue-depth snapshot. Returns
         whether the engine has work for a tick. Budget (TAX003):
         (0, 0) — the async layer itself never dispatches or syncs."""
-        self._apply_intake()
-        aborted = self._apply_timeouts() + self._apply_cancels()
-        if aborted:
-            # no tick may follow (the abort can drain the engine), so
-            # the metrics snapshot must pick up the abort counters here
-            self._refresh_metrics()
-        self._queued = len(self.engine.queue)
-        return bool(self.engine.queue or self.engine.active)
+        with self.spans.span("server.intake",
+                             tick=self.engine.tick_count + 1):
+            self._apply_intake()
+            aborted = self._apply_timeouts() + self._apply_cancels()
+            if aborted:
+                # no tick may follow (the abort can drain the engine),
+                # so the metrics snapshot must pick up the abort
+                # counters here
+                self._refresh_metrics()
+            self._queued = len(self.engine.queue)
+            return bool(self.engine.queue or self.engine.active)
 
     def _refresh_metrics(self) -> None:
-        self._metrics = dict(self.engine.metrics(self._done))
-        self._metrics["server_tick_failures"] = self.tick_failures
-        self._metrics["draining"] = self._draining
+        """Snapshot the counters between ticks. Only counters: the
+        work does not grow with the requests served."""
+        self._metrics = {
+            "requests": len(self._done),
+            "new_tokens": self._done_tokens,
+            **self.engine.counters(),
+            "server_tick_failures": self.tick_failures,
+            "draining": self._draining,
+            "server_gap_s": self.gap_s,
+            "server_gaps": self.gaps,
+            **self.spans.seconds,
+        }
+
+    def metrics(self) -> dict:
+        """``GET /v1/metrics``: the last snapshot, with the TTFT/TPOT
+        summaries over every finished request computed now."""
+        return {**self._metrics, **latency_metrics(self._done)}
+
+    def _record_done(self, req) -> None:
+        self._done.append(request_latency(req))
+        self._done_tokens += len(req.out_tokens)
 
     # ------------------------------------------------------ drain / failure
     def _begin_drain(self) -> None:
@@ -349,28 +400,30 @@ class Server:
         out NOW, one event per request per tick (tokens are buffered
         per-request between ticks by construction — the wire chunking
         mirrors the megatick, not a fake per-token stream)."""
-        for h in self._inflight.values():
-            new = h.req.out_tokens[h.sent:]
-            if new:
-                h.sent = len(h.req.out_tokens)
-                h.push("tokens", list(new))
-        for req in finished:
-            h = self._inflight.get(req.rid)
-            if h is None:
-                # orphan: a dropped-socket victim or a restored request
-                # with no reconnected stream — the work still counts
-                self._done.append(req)
-            elif not h.finished:
-                self._done.append(req)
-                if req.finish_reason == FINISH_ERROR:
-                    # poisoned slot: the engine retired THIS request
-                    # through the abort path; its co-batched neighbours
-                    # stream on token-identically
-                    self._fail(h, req.error or "request failed")
-                else:
-                    self._finish(h, req.finish_reason or FINISH_LENGTH)
-        self._drop_socket_fault()
-        self._refresh_metrics()
+        with self.spans.span("server.flush", tick=self.engine.tick_count):
+            for h in self._inflight.values():
+                new = h.req.out_tokens[h.sent:]
+                if new:
+                    h.sent = len(h.req.out_tokens)
+                    h.push("tokens", list(new))
+            for req in finished:
+                h = self._inflight.get(req.rid)
+                if h is None:
+                    # orphan: a dropped-socket victim or a restored
+                    # request with no reconnected stream — the work
+                    # still counts
+                    self._record_done(req)
+                elif not h.finished:
+                    self._record_done(req)
+                    if req.finish_reason == FINISH_ERROR:
+                        # poisoned slot: the engine retired THIS request
+                        # through the abort path; its co-batched
+                        # neighbours stream on token-identically
+                        self._fail(h, req.error or "request failed")
+                    else:
+                        self._finish(h, req.finish_reason or FINISH_LENGTH)
+            self._drop_socket_fault()
+            self._refresh_metrics()
 
     def _drop_socket_fault(self) -> None:
         """The one fault site the ENGINE cannot inject: a mid-stream
@@ -439,7 +492,8 @@ class Server:
                       temp=float(body.get("temp", 1.0)),
                       top_k=int(body.get("top_k", 0)),
                       priority=int(body.get("priority", 0)),
-                      deadline_ms=body.get("deadline_ms"))
+                      deadline_ms=body.get("deadline_ms"),
+                      arrived_t=time.monotonic())
         h = StreamHandle(rid=rid, req=req, events=asyncio.Queue(),
                          deadline=(time.monotonic() + float(timeout)
                                    if timeout is not None else None))
@@ -497,7 +551,7 @@ class Server:
                 and path.startswith("/v1/completions/"):
             await self._handle_cancel(path, writer)
         elif method == "GET" and path == "/v1/metrics":
-            await _send_json(writer, 200, dict(self._metrics))
+            await _send_json(writer, 200, self.metrics())
         elif method == "GET" and path == "/healthz":
             await _send_json(writer, 200, {
                 "ok": True,

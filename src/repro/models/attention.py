@@ -243,8 +243,11 @@ def decode_attn_step(params, x, cache, cur_len, cfg, active=None,
                 block_tables, scale=scale, window=cfg.sliding_window,
                 active=act, bounded=bounded)
         else:
-            ck = fd.paged_write(cache["k"], k[:, 0], block_tables, cl_b, act)
-            cv = fd.paged_write(cache["v"], v[:, 0], block_tables, cl_b, act)
+            with jax.named_scope("kv_write"):
+                ck = fd.paged_write(cache["k"], k[:, 0], block_tables, cl_b,
+                                    act)
+                cv = fd.paged_write(cache["v"], v[:, 0], block_tables, cl_b,
+                                    act)
             o = fd.reference_paged_decode_attention(
                 q[:, 0], ck, cv, cl_b, block_tables, scale,
                 window=cfg.sliding_window)

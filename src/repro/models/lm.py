@@ -298,18 +298,23 @@ def decode_step(params, token, state, cfg, active=None,
     # (B,1,d) activations are negligible next to the KV caches, which
     # stay batch-sharded). Measured: 0.76 GB -> ~0.02 GB per chip per
     # step on mistral-large (§Perf A4).
-    x = apply_embed(params["embed"], token, jnp.float32)
-    x = constrain(x, ctx.rules, None, None, "embed").astype(cfg.dtype)
-    if cfg.block == "rwkv":
-        x = apply_norm(params["ln_in"], x, "layernorm")
+    # named scopes (embed, attention, kv_write, mlp, lm_head, sample)
+    # name the source region of each op in a profile; the compiled
+    # program is the same
+    with jax.named_scope("embed"):
+        x = apply_embed(params["embed"], token, jnp.float32)
+        x = constrain(x, ctx.rules, None, None, "embed").astype(cfg.dtype)
+        if cfg.block == "rwkv":
+            x = apply_norm(params["ln_in"], x, "layernorm")
     bt = state.get("block_tables")
     btg = bt if (bt is None or gather_width is None) \
         else bt[:, :gather_width]
     x, caches = transformer.decode(params["backbone"], x, state["caches"],
                                    cur_len, cfg, active=active,
                                    block_tables=btg, bounded=bounded)
-    x = apply_norm(params["ln_f"], x, cfg.norm)
-    logits = logits_fn(params, x, cfg)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["ln_f"], x, cfg.norm)
+        logits = logits_fn(params, x, cfg)
     new_state = {"caches": caches, "cur_len": cur_len}
     if bt is not None:
         new_state["block_tables"] = bt
@@ -397,7 +402,8 @@ def decode_multi(params, token, state, cfg, *, steps: int, budgets,
         logits, st = decode_step(params, tok, st, cfg, active=act,
                                  gather_width=gather_width,
                                  bounded=bounded)
-        nxt = sample_fn(logits, j)
+        with jax.named_scope("sample"):
+            nxt = sample_fn(logits, j)
         tok = jnp.where(act[:, None], nxt, tok)
         return (st, tok), tok[:, 0]
 
@@ -476,7 +482,8 @@ def decode_mixed(params, tokens, token0, prefill_lens, emit_from, totals,
                                  gather_width=gather_width,
                                  bounded=bounded)
         emit = (j >= emit_from) & act
-        nxt = sample_fn(logits, j)
+        with jax.named_scope("sample"):
+            nxt = sample_fn(logits, j)
         tok = jnp.where(emit[:, None], nxt, tok)
         return (st, tok), tok[:, 0]
 

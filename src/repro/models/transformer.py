@@ -247,18 +247,18 @@ def decode(params, x, caches, cur_len, cfg, active=None, block_tables=None,
     if cfg.block in ("attn_mlp", "attn_moe"):
         def body(x, inp):
             lp, cache = inp
-            h = apply_norm(lp["ln1"], x, cfg.norm)
-            y, new_cache = attention.decode_attn_step(lp["attn"], h, cache,
-                                                      cur_len, cfg,
-                                                      active=active,
-                                                      block_tables=block_tables,
-                                                      bounded=bounded)
-            x = x + y
-            h = apply_norm(lp["ln2"], x, cfg.norm)
-            if "moe" in lp:
-                y, _ = moe.apply_moe(lp["moe"], h, cfg)
-            else:
-                y = mlp.apply_mlp_decode(lp["mlp"], h, cfg)
+            with jax.named_scope("attention"):
+                h = apply_norm(lp["ln1"], x, cfg.norm)
+                y, new_cache = attention.decode_attn_step(
+                    lp["attn"], h, cache, cur_len, cfg, active=active,
+                    block_tables=block_tables, bounded=bounded)
+                x = x + y
+            with jax.named_scope("mlp"):
+                h = apply_norm(lp["ln2"], x, cfg.norm)
+                if "moe" in lp:
+                    y, _ = moe.apply_moe(lp["moe"], h, cfg)
+                else:
+                    y = mlp.apply_mlp_decode(lp["mlp"], h, cfg)
             return x + y, new_cache
         if cfg.scan_layers:
             x, new_caches = lax.scan(body, x, (params["layers"], caches))

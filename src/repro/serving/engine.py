@@ -182,6 +182,20 @@ Per-request metrics: TTFT (submit -> first generated token) and TPOT
 (mean inter-token time over the generated tokens); engine metrics add
 p50/p99 latency tails, preemption/reclaim counters, and block
 occupancy + prefix-hit counters.
+
+Tracing (``serving.metrics.Spans``): every tick is an ``engine.tick``
+host span enclosing, in order, ``engine.admit`` (with the admitted
+rids), ``engine.prepare`` (reservations and host inputs),
+``engine.upload`` (``CachePool.sync``), ``engine.dispatch`` (the
+enqueue of the jitted program, retries included), ``engine.readback``
+(the one designed wait for the device) and ``engine.retire``; each
+span's seconds accumulate in ``engine_<phase>_s``. Beside them,
+``queue_wait_s``/``admissions`` time each request from arrival to its
+first admission, and ``scan_slot_steps``/``scan_slot_capacity`` count
+the slot-steps the dispatched programs did work in against the
+slot-steps they were compiled for (scan length x batch). The jitted
+programs carry the names ``megatick_mixed``, ``megatick_decode``,
+``step_single`` and ``step_chunk``.
 """
 from __future__ import annotations
 
@@ -201,9 +215,13 @@ from repro.serving.faults import (DISPATCH_ATTEMPTS, DegradedModeController,
                                   DispatchFailedError, FaultPlan,
                                   TransientDispatchError, backoff_s)
 from repro.serving.kv_cache import CachePool, pow2_bucket
-from repro.serving.metrics import latency_summary
+from repro.serving.metrics import Spans, latency_metrics, request_latency
 from repro.serving.scheduler import SchedulerPolicy, get_scheduler
 
+# the host spans of one tick: ``engine.tick`` encloses the rest, in order
+TICK_SPANS = ("engine.tick", "engine.admit", "engine.prepare",
+              "engine.upload", "engine.dispatch", "engine.readback",
+              "engine.retire")
 
 @dataclasses.dataclass
 class Request:
@@ -226,6 +244,7 @@ class Request:
     finish_reason: str | None = None  # "length" | "cancelled" | "error"
     error: str | None = None         # human-readable poison/fault reason
     submitted_t: float = 0.0
+    arrived_t: float = 0.0           # monotonic: reached the system
     admitted_t: float = 0.0
     first_token_t: float = 0.0
     finished_t: float = 0.0
@@ -397,6 +416,14 @@ class Engine:
         self.error_count = 0             # slots retired finish_reason=error
         self.slow_tick_count = 0         # watchdog-flagged megaticks
         self.drain_count = 0             # requests parked by drain()
+        # -------- tracing: host spans of each tick and their seconds,
+        # queue wait to first admission, and scan fill (slot-steps that
+        # did work against scan length x batch, over every dispatch)
+        self.spans = Spans(*TICK_SPANS)
+        self.queue_wait_s = 0.0
+        self.admission_count = 0
+        self.scan_slot_steps = 0
+        self.scan_slot_capacity = 0
         self._build_dispatchers()
 
     def _build_dispatchers(self):
@@ -409,10 +436,13 @@ class Engine:
         sampler = self.sampler
         ctx = self.ctx
 
-        def under_ctx(fn):
+        def under_ctx(fn, name):
             def traced(*args):
                 with dctx.use(ctx):
                     return fn(*args)
+            # the program's name: jit(<name>) in its HLO module and on
+            # a trace's XLA Modules line
+            traced.__name__ = traced.__qualname__ = name
             return traced
 
         # two jitted paths sharing the pool state: a 1-token step for
@@ -423,12 +453,12 @@ class Engine:
         bounded = self.bounded_gather
         self._step1 = jax.jit(under_ctx(
             lambda p, t, a, s, gw: lm.decode_step(
-                p, t, s, cfg, active=a, gather_width=gw, bounded=bounded)),
-            static_argnums=(4,))
+                p, t, s, cfg, active=a, gather_width=gw, bounded=bounded),
+            "step_single"), static_argnums=(4,))
         self._stepC = jax.jit(under_ctx(
             lambda p, t, c, s, gw: lm.decode_chunk(
-                p, t, c, s, cfg, gather_width=gw, bounded=bounded)),
-            static_argnums=(4,))
+                p, t, c, s, cfg, gather_width=gw, bounded=bounded),
+            "step_chunk"), static_argnums=(4,))
         # the K-step decode megatick: sampling runs INSIDE the scan
         # (greedy argmax, or the seeded batch sampler whose keys fold
         # (seed, rid, token index) with the scan step offsetting each
@@ -449,7 +479,7 @@ class Engine:
                                    sample_fn=sample_fn, gather_width=gw,
                                    bounded=bounded)
 
-        self._stepK = jax.jit(under_ctx(_megatick_fn),
+        self._stepK = jax.jit(under_ctx(_megatick_fn, "megatick_decode"),
                               static_argnums=(8, 9))
 
         # the mixed prefill+decode megatick: one fused program in which
@@ -472,7 +502,7 @@ class Engine:
                                    steps=S, sample_fn=sample_fn,
                                    gather_width=gw, bounded=bounded)
 
-        self._stepM = jax.jit(under_ctx(_mixedtick_fn),
+        self._stepM = jax.jit(under_ctx(_mixedtick_fn, "megatick_mixed"),
                               static_argnums=(11, 12))
         self._sample = jax.jit(sampler_lib.sample_batch)
         self._greedy = jax.jit(sampler_lib.greedy)
@@ -501,6 +531,8 @@ class Engine:
                 f"{self.pool.block_size}) — it could never be admitted; "
                 f"raise n_blocks")
         req.submitted_t = time.time()
+        if not req.arrived_t:           # the server stamps it at accept
+            req.arrived_t = time.monotonic()
         req.seq = self._seq
         self._seq += 1
         if at_tick is not None:
@@ -532,6 +564,11 @@ class Engine:
             slot, reused = res
             req.slot = slot
             req.consumed = req.reused_tokens = reused
+            if not req.admitted_t:
+                # first admission: its wait counts once — re-admissions
+                # after a preemption or a drain do not count again
+                self.queue_wait_s += time.monotonic() - req.arrived_t
+                self.admission_count += 1
             req.admitted_t = time.time()
             self.active[slot] = req
             taken.add(id(req))
@@ -718,9 +755,9 @@ class Engine:
         slots) and steps K/gather-mode/shedding down under sustained
         pressure, back up after sustained health."""
         r0, e0 = self.dispatch_retry_count, self.error_count
-        t0 = time.monotonic()
-        finished = self._tick()
-        slow = self.watchdog.timed(self.tick_count, t0)
+        with self.spans.span("engine.tick", tick=self.tick_count + 1) as sp:
+            finished = self._tick()
+        slow = self.watchdog.record(self.tick_count, sp.seconds)
         if slow:
             self.slow_tick_count += 1
         if self.degraded is not None:
@@ -735,7 +772,10 @@ class Engine:
         return finished
 
     def _tick(self) -> list[Request]:
-        self._admit()
+        with self.spans.span("engine.admit", tick=self.tick_count + 1) as sp:
+            admitted = self._admit()
+            if admitted:
+                sp.set_metadata(rids=" ".join(str(r.rid) for r in admitted))
         self.tick_count += 1
         self._apply_faults()
         if not self.active:
@@ -749,28 +789,31 @@ class Engine:
                 return self._megatick_mixed()
             return self._megatick()
         C = self.prefill_chunk
-        tok = np.zeros((self.batch, C), np.int32)
-        cnt = np.zeros((self.batch,), np.int32)
-        emit = np.zeros((self.batch,), bool)
-        any_prefill = False
-        for slot, req in self.active.items():
-            want = (min(C, len(req.eff_prompt) - req.consumed)
-                    if req.prefilling else 1)
-            # clamp to what the pool can back with blocks this tick
-            # (allocates at chunk boundaries, copy-on-writes shared blocks)
-            n = self.pool.writable(slot, want)
-            if n == 0:
-                continue                    # stalled: no KV block free
-            if req.prefilling:
-                any_prefill = True
-                tok[slot, :n] = req.eff_prompt[req.consumed:req.consumed + n]
-                cnt[slot] = n
-                emit[slot] = req.consumed + n >= len(req.eff_prompt)
-            else:
-                tok[slot, 0] = (req.out_tokens[-1] if req.out_tokens
-                                else req.eff_prompt[-1])
-                cnt[slot] = 1
-                emit[slot] = True
+        with self.spans.span("engine.prepare", tick=self.tick_count):
+            tok = np.zeros((self.batch, C), np.int32)
+            cnt = np.zeros((self.batch,), np.int32)
+            emit = np.zeros((self.batch,), bool)
+            any_prefill = False
+            for slot, req in self.active.items():
+                want = (min(C, len(req.eff_prompt) - req.consumed)
+                        if req.prefilling else 1)
+                # clamp to what the pool can back with blocks this tick
+                # (allocates at chunk boundaries, copy-on-writes shared
+                # blocks)
+                n = self.pool.writable(slot, want)
+                if n == 0:
+                    continue                # stalled: no KV block free
+                if req.prefilling:
+                    any_prefill = True
+                    tok[slot, :n] = \
+                        req.eff_prompt[req.consumed:req.consumed + n]
+                    cnt[slot] = n
+                    emit[slot] = req.consumed + n >= len(req.eff_prompt)
+                else:
+                    tok[slot, 0] = (req.out_tokens[-1] if req.out_tokens
+                                    else req.eff_prompt[-1])
+                    cnt[slot] = 1
+                    emit[slot] = True
 
         cmax = int(cnt.max(initial=0))
         if cmax == 0:
@@ -779,97 +822,105 @@ class Engine:
             # blocks unblock the survivors next tick
             self._preempt_one()
             return []
-        self.pool.sync()
+        with self.spans.span("engine.upload", tick=self.tick_count):
+            self.pool.sync()
         # gather width AFTER the writable() loop: this tick's block
         # allocations are in the table, so the bucket covers every
         # position the jitted step will read or write
         gw = self.pool.gather_width()
+        # bucket the scan length to the next power of two so ticks with
+        # little prefill left don't pay the full chunk, while compile
+        # count stays bounded at log2(prefill_chunk)
+        cw = 1 if cmax <= 1 else pow2_bucket(cmax, C)
         self.dispatch_count += 1
         if not any_prefill:
             self.decode_dispatch_count += 1
+        self.scan_slot_steps += int(cnt.sum())
+        self.scan_slot_capacity += cw * self.batch
         # bounded retry-with-backoff around the ONE jitted dispatch:
         # pool state commits only on success, so a retried attempt
         # replays identical inputs (transient failures are safe to
         # retry; retries count against the TAX003 budget as real
         # worst-case dispatches — DISPATCH_ATTEMPTS is a static trip)
-        fault = self._poll_fault("dispatch")
-        for attempt in range(DISPATCH_ATTEMPTS):
-            if attempt:
-                self._backoff(attempt)
-            try:
-                if fault is not None:
-                    fault.trip()
-                if cmax <= 1:
-                    logits, state = self._step1(
-                        self.params, jnp.asarray(tok[:, :1]),
-                        jnp.asarray(cnt > 0), self.pool.state, gw)
-                else:
-                    # bucket the scan length to the next power of two so
-                    # ticks with little prefill left don't pay the full
-                    # chunk, while compile count stays bounded at
-                    # log2(prefill_chunk)
-                    cw = pow2_bucket(cmax, C)
-                    logits, state = self._stepC(
-                        self.params, jnp.asarray(tok[:, :cw]),
-                        jnp.asarray(cnt), self.pool.state, gw)
-                break
-            except TransientDispatchError as err:
-                last_err = err
-        else:
-            self.dispatch_failure_count += 1
-            raise DispatchFailedError(
-                f"dispatch failed after {DISPATCH_ATTEMPTS} attempts at "
-                f"tick {self.tick_count}") from last_err
-        self.pool.state = state
-        nxt = self._next_tokens(logits, emit)
-        poison = self._poll_fault("tokens")
-        if poison is not None:
-            # the host-visible signature of NaN/Inf logits: a garbage
-            # (out-of-range) sampled id for exactly one slot
-            nxt[poison.slot % self.batch, :] = -1
+        with self.spans.span("engine.dispatch", tick=self.tick_count):
+            fault = self._poll_fault("dispatch")
+            for attempt in range(DISPATCH_ATTEMPTS):
+                if attempt:
+                    self._backoff(attempt)
+                try:
+                    if fault is not None:
+                        fault.trip()
+                    if cmax <= 1:
+                        logits, state = self._step1(
+                            self.params, jnp.asarray(tok[:, :1]),
+                            jnp.asarray(cnt > 0), self.pool.state, gw)
+                    else:
+                        logits, state = self._stepC(
+                            self.params, jnp.asarray(tok[:, :cw]),
+                            jnp.asarray(cnt), self.pool.state, gw)
+                    break
+                except TransientDispatchError as err:
+                    last_err = err
+            else:
+                self.dispatch_failure_count += 1
+                raise DispatchFailedError(
+                    f"dispatch failed after {DISPATCH_ATTEMPTS} attempts "
+                    f"at tick {self.tick_count}") from last_err
+            self.pool.state = state
+        with self.spans.span("engine.readback", tick=self.tick_count):
+            nxt = self._next_tokens(logits, emit)
 
-        finished = []
-        now = time.time()
-        for slot, req in list(self.active.items()):
-            n = int(cnt[slot])
-            if n == 0:
-                continue
-            self.pool.advance(slot, n)
-            cache_full = int(self.pool.lengths[slot]) + 1 >= self.max_len
-            if req.prefilling:
-                req.consumed += n
-                # full prompt chunks just written become shareable
-                # prefix blocks for future admissions (and for resuming
-                # this request if it is ever preempted)
-                self.pool.register_prompt_chunks(slot, req.eff_prompt)
-            if self.cfg.sliding_window is not None:
-                # block-level reclaim: positions that rolled permanently
-                # out of the window stop pinning their blocks
-                self.pool.reclaim_out_of_window(slot,
-                                                self.cfg.sliding_window)
-            if req.prefilling and not cache_full:   # still mid-prompt
-                continue
-            if not req.prefilling:
-                # the logits after this slot's last consumed token give
-                # the next output token (the first one arrives on the
-                # tick that completes the prefill)
-                t = int(nxt[slot, 0])
-                if not 0 <= t < self.cfg.vocab_size:
-                    # NaN/Inf guard: a sampled id outside the vocab is
-                    # the readback signature of non-finite logits —
-                    # retire THIS slot as an error, survivors untouched
-                    self._retire_error(
-                        slot, req, now, finished,
-                        f"non-finite logits: sampled token id {t}")
+        with self.spans.span("engine.retire", tick=self.tick_count):
+            poison = self._poll_fault("tokens")
+            if poison is not None:
+                # the host-visible signature of NaN/Inf logits: a
+                # garbage (out-of-range) sampled id for exactly one slot
+                nxt[poison.slot % self.batch, :] = -1
+
+            finished = []
+            now = time.time()
+            for slot, req in list(self.active.items()):
+                n = int(cnt[slot])
+                if n == 0:
                     continue
-                req.out_tokens.append(t)
-                if not any_prefill:
-                    self.decode_token_count += 1
-                if len(req.out_tokens) == 1:
-                    req.first_token_t = now
-            if (len(req.out_tokens) >= req.max_new_tokens
-                    or cache_full):
-                self._retire(slot, req, now, finished)
+                self.pool.advance(slot, n)
+                cache_full = int(self.pool.lengths[slot]) + 1 >= self.max_len
+                if req.prefilling:
+                    req.consumed += n
+                    # full prompt chunks just written become shareable
+                    # prefix blocks for future admissions (and for
+                    # resuming this request if it is ever preempted)
+                    self.pool.register_prompt_chunks(slot, req.eff_prompt)
+                if self.cfg.sliding_window is not None:
+                    # block-level reclaim: positions that rolled
+                    # permanently out of the window stop pinning their
+                    # blocks
+                    self.pool.reclaim_out_of_window(slot,
+                                                    self.cfg.sliding_window)
+                if req.prefilling and not cache_full:   # still mid-prompt
+                    continue
+                if not req.prefilling:
+                    # the logits after this slot's last consumed token
+                    # give the next output token (the first one arrives
+                    # on the tick that completes the prefill)
+                    t = int(nxt[slot, 0])
+                    if not 0 <= t < self.cfg.vocab_size:
+                        # NaN/Inf guard: a sampled id outside the vocab
+                        # is the readback signature of non-finite logits
+                        # — retire THIS slot as an error, survivors
+                        # untouched
+                        self._retire_error(
+                            slot, req, now, finished,
+                            f"non-finite logits: sampled token id {t}")
+                        continue
+                    req.out_tokens.append(t)
+                    if not any_prefill:
+                        self.decode_token_count += 1
+                    if len(req.out_tokens) == 1:
+                        req.first_token_t = now
+                if (len(req.out_tokens) >= req.max_new_tokens
+                        or cache_full):
+                    self._retire(slot, req, now, finished)
         return finished
 
     def _megatick(self) -> list[Request]:
@@ -881,25 +932,26 @@ class Engine:
         byte-identically inside the scan. Sampling is device-resident —
         the host gets back (B, K) token ids, not K logit tensors."""
         K = self.eff_decode_steps
-        tok = np.zeros((self.batch, 1), np.int32)
-        budgets = np.zeros((self.batch,), np.int32)
-        rids = np.zeros((self.batch,), np.int32)
-        steps0 = np.zeros((self.batch,), np.int32)
-        temps = np.zeros((self.batch,), np.float32)
-        topks = np.zeros((self.batch,), np.int32)
-        for slot, req in self.active.items():
-            # a live decode slot always wants >= 1 step (it would have
-            # been retired last tick otherwise); the reservation may
-            # still return 0 under pool pressure -> the slot stalls
-            want = min(K, req.max_new_tokens - len(req.out_tokens),
-                       self.max_len - 1 - int(self.pool.lengths[slot]))
-            budgets[slot] = self.pool.reserve(slot, want)
-            tok[slot, 0] = (req.out_tokens[-1] if req.out_tokens
-                            else req.eff_prompt[-1])
-            rids[slot] = req.rid
-            steps0[slot] = len(req.out_tokens)
-            temps[slot] = req.temp
-            topks[slot] = req.top_k
+        with self.spans.span("engine.prepare", tick=self.tick_count):
+            tok = np.zeros((self.batch, 1), np.int32)
+            budgets = np.zeros((self.batch,), np.int32)
+            rids = np.zeros((self.batch,), np.int32)
+            steps0 = np.zeros((self.batch,), np.int32)
+            temps = np.zeros((self.batch,), np.float32)
+            topks = np.zeros((self.batch,), np.int32)
+            for slot, req in self.active.items():
+                # a live decode slot always wants >= 1 step (it would have
+                # been retired last tick otherwise); the reservation may
+                # still return 0 under pool pressure -> the slot stalls
+                want = min(K, req.max_new_tokens - len(req.out_tokens),
+                           self.max_len - 1 - int(self.pool.lengths[slot]))
+                budgets[slot] = self.pool.reserve(slot, want)
+                tok[slot, 0] = (req.out_tokens[-1] if req.out_tokens
+                                else req.eff_prompt[-1])
+                rids[slot] = req.rid
+                steps0[slot] = len(req.out_tokens)
+                temps[slot] = req.temp
+                topks[slot] = req.top_k
         kmax = int(budgets.max(initial=0))
         if kmax == 0:
             # every slot stalled on block availability at the megatick
@@ -907,7 +959,8 @@ class Engine:
             # path does
             self._preempt_one()
             return []
-        self.pool.sync()
+        with self.spans.span("engine.upload", tick=self.tick_count):
+            self.pool.sync()
         # gather width AFTER the reserve() loop: the static slice must
         # cover every block the whole megatick writes
         gw = self.pool.gather_width()
@@ -917,72 +970,78 @@ class Engine:
         kb = pow2_bucket(kmax, K)
         self.dispatch_count += 1
         self.decode_dispatch_count += 1
+        self.scan_slot_steps += int(budgets.sum())
+        self.scan_slot_capacity += kb * self.batch
         # bounded retry-with-backoff: pool state commits only on
         # success, so a retried attempt replays identical inputs
-        fault = self._poll_fault("dispatch")
-        for attempt in range(DISPATCH_ATTEMPTS):
-            if attempt:
-                self._backoff(attempt)
-            try:
-                if fault is not None:
-                    fault.trip()
-                out, state = self._stepK(
-                    self.params, jnp.asarray(tok), jnp.asarray(budgets),
-                    self.pool.state, jnp.asarray(rids),
-                    jnp.asarray(steps0), jnp.asarray(temps),
-                    jnp.asarray(topks), kb, gw)
-                break
-            except TransientDispatchError as err:
-                last_err = err
-        else:
-            self.dispatch_failure_count += 1
-            raise DispatchFailedError(
-                f"megatick dispatch failed after {DISPATCH_ATTEMPTS} "
-                f"attempts at tick {self.tick_count}") from last_err
-        self.pool.state = state
-        # taxlint: ignore[TAX001] the megatick's ONE designed sync: (B, K)
-        # token ids — not K logit tensors — come back to drive Python-side
-        # scheduling; amortized over K tokens, this IS the 1/K bound
-        out = np.asarray(out)
-        poison = self._poll_fault("tokens")
-        if poison is not None:
-            # the host-visible signature of NaN/Inf logits mid-megatick
-            out = out.copy()
-            out[poison.slot % self.batch, :] = -1
+        with self.spans.span("engine.dispatch", tick=self.tick_count):
+            fault = self._poll_fault("dispatch")
+            for attempt in range(DISPATCH_ATTEMPTS):
+                if attempt:
+                    self._backoff(attempt)
+                try:
+                    if fault is not None:
+                        fault.trip()
+                    out, state = self._stepK(
+                        self.params, jnp.asarray(tok), jnp.asarray(budgets),
+                        self.pool.state, jnp.asarray(rids),
+                        jnp.asarray(steps0), jnp.asarray(temps),
+                        jnp.asarray(topks), kb, gw)
+                    break
+                except TransientDispatchError as err:
+                    last_err = err
+            else:
+                self.dispatch_failure_count += 1
+                raise DispatchFailedError(
+                    f"megatick dispatch failed after {DISPATCH_ATTEMPTS} "
+                    f"attempts at tick {self.tick_count}") from last_err
+            self.pool.state = state
+        with self.spans.span("engine.readback", tick=self.tick_count):
+            # taxlint: ignore[TAX001] the megatick's ONE designed sync:
+            # (B, K) token ids — not K logit tensors — come back to drive
+            # Python-side scheduling; amortized over K tokens, this IS the
+            # 1/K bound
+            out = np.asarray(out)
+        with self.spans.span("engine.retire", tick=self.tick_count):
+            poison = self._poll_fault("tokens")
+            if poison is not None:
+                # the host-visible signature of NaN/Inf logits mid-megatick
+                out = out.copy()
+                out[poison.slot % self.batch, :] = -1
 
-        finished = []
-        now = time.time()
-        for slot, req in list(self.active.items()):
-            n = int(budgets[slot])
-            if n == 0:
-                continue
-            row = out[slot, :n]
-            bad = np.nonzero((row < 0) | (row >= self.cfg.vocab_size))[0]
-            if bad.size:
-                # NaN/Inf guard: keep the tokens sampled BEFORE the
-                # first garbage id (their logits were still finite),
-                # advance the host length mirror only that far so the
-                # prefix registry can never serve poisoned KV, and
-                # retire THIS slot as an error — survivors untouched
-                good = int(bad[0])
-                self.pool.advance(slot, good)
-                req.out_tokens.extend(int(t) for t in row[:good])
-                self.decode_token_count += good
-                self._retire_error(
-                    slot, req, now, finished,
-                    f"non-finite logits: sampled token id "
-                    f"{int(row[good])}")
-                continue
-            self.pool.advance(slot, n)
-            req.out_tokens.extend(int(t) for t in row)
-            self.decode_token_count += n
-            if self.cfg.sliding_window is not None:
-                self.pool.reclaim_out_of_window(slot,
-                                                self.cfg.sliding_window)
-            cache_full = int(self.pool.lengths[slot]) + 1 >= self.max_len
-            if (len(req.out_tokens) >= req.max_new_tokens
-                    or cache_full):
-                self._retire(slot, req, now, finished)
+            finished = []
+            now = time.time()
+            for slot, req in list(self.active.items()):
+                n = int(budgets[slot])
+                if n == 0:
+                    continue
+                row = out[slot, :n]
+                bad = np.nonzero((row < 0) | (row >= self.cfg.vocab_size))[0]
+                if bad.size:
+                    # NaN/Inf guard: keep the tokens sampled BEFORE the
+                    # first garbage id (their logits were still finite),
+                    # advance the host length mirror only that far so the
+                    # prefix registry can never serve poisoned KV, and
+                    # retire THIS slot as an error — survivors untouched
+                    good = int(bad[0])
+                    self.pool.advance(slot, good)
+                    req.out_tokens.extend(int(t) for t in row[:good])
+                    self.decode_token_count += good
+                    self._retire_error(
+                        slot, req, now, finished,
+                        f"non-finite logits: sampled token id "
+                        f"{int(row[good])}")
+                    continue
+                self.pool.advance(slot, n)
+                req.out_tokens.extend(int(t) for t in row)
+                self.decode_token_count += n
+                if self.cfg.sliding_window is not None:
+                    self.pool.reclaim_out_of_window(slot,
+                                                    self.cfg.sliding_window)
+                cache_full = int(self.pool.lengths[slot]) + 1 >= self.max_len
+                if (len(req.out_tokens) >= req.max_new_tokens
+                        or cache_full):
+                    self._retire(slot, req, now, finished)
         return finished
 
     def _megatick_mixed(self) -> list[Request]:
@@ -1011,48 +1070,49 @@ class Engine:
         every other dispatch path does."""
         K = self.eff_decode_steps
         M = self.megatick_tokens
-        toks = np.zeros((self.batch, M), np.int32)
-        tok0 = np.zeros((self.batch, 1), np.int32)
-        pl = np.zeros((self.batch,), np.int32)     # prefill role steps
-        e0 = np.zeros((self.batch,), np.int32)     # first emitting step
-        tot = np.zeros((self.batch,), np.int32)    # total active steps
-        rids = np.zeros((self.batch,), np.int32)
-        steps0 = np.zeros((self.batch,), np.int32)
-        temps = np.zeros((self.batch,), np.float32)
-        topks = np.zeros((self.batch,), np.int32)
-        for slot, req in self.active.items():
-            headroom = self.max_len - 1 - int(self.pool.lengths[slot])
-            rem_new = req.max_new_tokens - len(req.out_tokens)
-            if req.prefilling:
-                rem_p = len(req.eff_prompt) - req.consumed
-                p_want = min(M, rem_p)
-                # decode piggybacking only when the prompt completes
-                # inside this megatick; the first sampled token is free
-                # (its KV write happens when it is consumed), so the
-                # decode span is capped at remaining max_new MINUS one
-                d_want = (max(0, min(M - p_want, K, rem_new - 1,
-                                     headroom - p_want))
-                          if p_want == rem_p else 0)
-            else:
-                rem_p = 0
-                p_want = 0
-                d_want = min(K, rem_new, headroom)
-            n = self.pool.reserve(slot, p_want + d_want)
-            p = min(n, p_want)
-            tot[slot] = n
-            pl[slot] = p
-            # emission starts at the step consuming the LAST prompt
-            # token (first sampled token rides its logits) — or at step
-            # 0 for slots already decoding; a slot whose prompt does
-            # not complete this megatick never emits (e0 == n)
-            e0[slot] = max(p - 1, 0) if p == rem_p else n
-            toks[slot, :p] = req.eff_prompt[req.consumed:req.consumed + p]
-            tok0[slot, 0] = (req.out_tokens[-1] if req.out_tokens
-                             else req.eff_prompt[-1])
-            rids[slot] = req.rid
-            steps0[slot] = len(req.out_tokens)
-            temps[slot] = req.temp
-            topks[slot] = req.top_k
+        with self.spans.span("engine.prepare", tick=self.tick_count):
+            toks = np.zeros((self.batch, M), np.int32)
+            tok0 = np.zeros((self.batch, 1), np.int32)
+            pl = np.zeros((self.batch,), np.int32)     # prefill role steps
+            e0 = np.zeros((self.batch,), np.int32)     # first emitting step
+            tot = np.zeros((self.batch,), np.int32)    # total active steps
+            rids = np.zeros((self.batch,), np.int32)
+            steps0 = np.zeros((self.batch,), np.int32)
+            temps = np.zeros((self.batch,), np.float32)
+            topks = np.zeros((self.batch,), np.int32)
+            for slot, req in self.active.items():
+                headroom = self.max_len - 1 - int(self.pool.lengths[slot])
+                rem_new = req.max_new_tokens - len(req.out_tokens)
+                if req.prefilling:
+                    rem_p = len(req.eff_prompt) - req.consumed
+                    p_want = min(M, rem_p)
+                    # decode piggybacking only when the prompt completes
+                    # inside this megatick; the first sampled token is free
+                    # (its KV write happens when it is consumed), so the
+                    # decode span is capped at remaining max_new MINUS one
+                    d_want = (max(0, min(M - p_want, K, rem_new - 1,
+                                         headroom - p_want))
+                              if p_want == rem_p else 0)
+                else:
+                    rem_p = 0
+                    p_want = 0
+                    d_want = min(K, rem_new, headroom)
+                n = self.pool.reserve(slot, p_want + d_want)
+                p = min(n, p_want)
+                tot[slot] = n
+                pl[slot] = p
+                # emission starts at the step consuming the LAST prompt
+                # token (first sampled token rides its logits) — or at step
+                # 0 for slots already decoding; a slot whose prompt does
+                # not complete this megatick never emits (e0 == n)
+                e0[slot] = max(p - 1, 0) if p == rem_p else n
+                toks[slot, :p] = req.eff_prompt[req.consumed:req.consumed + p]
+                tok0[slot, 0] = (req.out_tokens[-1] if req.out_tokens
+                                 else req.eff_prompt[-1])
+                rids[slot] = req.rid
+                steps0[slot] = len(req.out_tokens)
+                temps[slot] = req.temp
+                topks[slot] = req.top_k
         nmax = int(tot.max(initial=0))
         if nmax == 0:
             # every slot stalled on block availability at the megatick
@@ -1060,7 +1120,8 @@ class Engine:
             # dispatch paths do
             self._preempt_one()
             return []
-        self.pool.sync()
+        with self.spans.span("engine.upload", tick=self.tick_count):
+            self.pool.sync()
         # gather width AFTER the reserve() loop: the static slice must
         # cover every block the whole megatick writes, prompt chunks
         # included
@@ -1072,94 +1133,99 @@ class Engine:
         self.dispatch_count += 1
         self.mixed_dispatch_count += 1
         self.mixed_prompt_token_count += int(pl.sum())
+        self.scan_slot_steps += int(tot.sum())
+        self.scan_slot_capacity += S * self.batch
         # bounded retry-with-backoff: pool state commits only on
         # success, so a retried attempt replays identical inputs
-        fault = self._poll_fault("dispatch")
-        for attempt in range(DISPATCH_ATTEMPTS):
-            if attempt:
-                self._backoff(attempt)
-            try:
-                if fault is not None:
-                    fault.trip()
-                out, state = self._stepM(
-                    self.params, jnp.asarray(toks[:, :S]),
-                    jnp.asarray(tok0), jnp.asarray(pl), jnp.asarray(e0),
-                    jnp.asarray(tot), self.pool.state, jnp.asarray(rids),
-                    jnp.asarray(steps0), jnp.asarray(temps),
-                    jnp.asarray(topks), S, gw)
-                break
-            except TransientDispatchError as err:
-                last_err = err
-        else:
-            self.dispatch_failure_count += 1
-            raise DispatchFailedError(
-                f"mixed megatick dispatch failed after "
-                f"{DISPATCH_ATTEMPTS} attempts at tick "
-                f"{self.tick_count}") from last_err
-        self.pool.state = state
-        # taxlint: ignore[TAX001] the mixed megatick's ONE designed sync:
-        # (B, S) sampled-token ids — not per-step logit tensors — come
-        # back to drive Python-side scheduling; amortized over the
-        # megatick's prompt+decode tokens, this IS the 1/K bound under
-        # continuous arrivals
-        out = np.asarray(out)
-        poison = self._poll_fault("tokens")
-        if poison is not None:
-            # the host-visible signature of NaN/Inf logits mid-megatick
-            out = out.copy()
-            out[poison.slot % self.batch, :] = -1
+        with self.spans.span("engine.dispatch", tick=self.tick_count):
+            fault = self._poll_fault("dispatch")
+            for attempt in range(DISPATCH_ATTEMPTS):
+                if attempt:
+                    self._backoff(attempt)
+                try:
+                    if fault is not None:
+                        fault.trip()
+                    out, state = self._stepM(
+                        self.params, jnp.asarray(toks[:, :S]),
+                        jnp.asarray(tok0), jnp.asarray(pl), jnp.asarray(e0),
+                        jnp.asarray(tot), self.pool.state, jnp.asarray(rids),
+                        jnp.asarray(steps0), jnp.asarray(temps),
+                        jnp.asarray(topks), S, gw)
+                    break
+                except TransientDispatchError as err:
+                    last_err = err
+            else:
+                self.dispatch_failure_count += 1
+                raise DispatchFailedError(
+                    f"mixed megatick dispatch failed after "
+                    f"{DISPATCH_ATTEMPTS} attempts at tick "
+                    f"{self.tick_count}") from last_err
+            self.pool.state = state
+        with self.spans.span("engine.readback", tick=self.tick_count):
+            # taxlint: ignore[TAX001] the mixed megatick's ONE designed sync:
+            # (B, S) sampled-token ids — not per-step logit tensors — come
+            # back to drive Python-side scheduling; amortized over the
+            # megatick's prompt+decode tokens, this IS the 1/K bound under
+            # continuous arrivals
+            out = np.asarray(out)
+        with self.spans.span("engine.retire", tick=self.tick_count):
+            poison = self._poll_fault("tokens")
+            if poison is not None:
+                # the host-visible signature of NaN/Inf logits mid-megatick
+                out = out.copy()
+                out[poison.slot % self.batch, :] = -1
 
-        finished = []
-        now = time.time()
-        for slot, req in list(self.active.items()):
-            n = int(tot[slot])
-            if n == 0:
-                continue
-            p = int(pl[slot])
-            first_emit = int(e0[slot])
-            emitted = n - first_emit
-            span = out[slot, first_emit:n] if emitted > 0 \
-                else out[slot, :0]
-            bad = np.nonzero((span < 0)
-                             | (span >= self.cfg.vocab_size))[0]
-            if bad.size:
-                # NaN/Inf guard, mixed shape: prompt-chunk writes are
-                # real tokens (always clean); of the sampled span keep
-                # only the ids before the first garbage one. Advance
-                # the host length mirror over prompt writes + clean
-                # sampled writes so the prefix registry never serves
-                # poisoned KV, then retire THIS slot as an error.
-                good = int(bad[0])
-                self.pool.advance(slot, min(n, p + good))
-                req.consumed += p
-                req.out_tokens.extend(int(t) for t in span[:good])
-                self.mixed_decode_token_count += good
-                self._retire_error(
-                    slot, req, now, finished,
-                    f"non-finite logits: sampled token id "
-                    f"{int(span[good])}")
-                continue
-            self.pool.advance(slot, n)
-            if p:
-                req.consumed += p
-                # full prompt chunks just written become shareable
-                # prefix blocks, exactly as on a single-step tick
-                self.pool.register_prompt_chunks(slot, req.eff_prompt)
-            if self.cfg.sliding_window is not None:
-                self.pool.reclaim_out_of_window(slot,
-                                                self.cfg.sliding_window)
-            if emitted > 0:
-                first = not req.out_tokens
-                req.out_tokens.extend(int(t) for t in span)
-                self.mixed_decode_token_count += emitted
-                if first:
-                    req.first_token_t = now
-            cache_full = int(self.pool.lengths[slot]) + 1 >= self.max_len
-            if req.prefilling and not cache_full:
-                continue
-            if (len(req.out_tokens) >= req.max_new_tokens
-                    or cache_full):
-                self._retire(slot, req, now, finished)
+            finished = []
+            now = time.time()
+            for slot, req in list(self.active.items()):
+                n = int(tot[slot])
+                if n == 0:
+                    continue
+                p = int(pl[slot])
+                first_emit = int(e0[slot])
+                emitted = n - first_emit
+                span = out[slot, first_emit:n] if emitted > 0 \
+                    else out[slot, :0]
+                bad = np.nonzero((span < 0)
+                                 | (span >= self.cfg.vocab_size))[0]
+                if bad.size:
+                    # NaN/Inf guard, mixed shape: prompt-chunk writes are
+                    # real tokens (always clean); of the sampled span keep
+                    # only the ids before the first garbage one. Advance
+                    # the host length mirror over prompt writes + clean
+                    # sampled writes so the prefix registry never serves
+                    # poisoned KV, then retire THIS slot as an error.
+                    good = int(bad[0])
+                    self.pool.advance(slot, min(n, p + good))
+                    req.consumed += p
+                    req.out_tokens.extend(int(t) for t in span[:good])
+                    self.mixed_decode_token_count += good
+                    self._retire_error(
+                        slot, req, now, finished,
+                        f"non-finite logits: sampled token id "
+                        f"{int(span[good])}")
+                    continue
+                self.pool.advance(slot, n)
+                if p:
+                    req.consumed += p
+                    # full prompt chunks just written become shareable
+                    # prefix blocks, exactly as on a single-step tick
+                    self.pool.register_prompt_chunks(slot, req.eff_prompt)
+                if self.cfg.sliding_window is not None:
+                    self.pool.reclaim_out_of_window(slot,
+                                                    self.cfg.sliding_window)
+                if emitted > 0:
+                    first = not req.out_tokens
+                    req.out_tokens.extend(int(t) for t in span)
+                    self.mixed_decode_token_count += emitted
+                    if first:
+                        req.first_token_t = now
+                cache_full = int(self.pool.lengths[slot]) + 1 >= self.max_len
+                if req.prefilling and not cache_full:
+                    continue
+                if (len(req.out_tokens) >= req.max_new_tokens
+                        or cache_full):
+                    self._retire(slot, req, now, finished)
         return finished
 
     def _next_tokens(self, logits, emit):
@@ -1307,6 +1373,7 @@ class Engine:
             r.seq = d["seq"]
             r.submitted_t = d["submitted_t"]
             r.first_token_t = d["first_token_t"]
+            r.arrived_t = time.monotonic()
             r.arrival_tick = 0          # admissible immediately
             self.queue.append(r)
             restored.append(r)
@@ -1315,14 +1382,19 @@ class Engine:
 
     # -------------------------------------------------------------- metrics
     def metrics(self, done: list[Request]) -> dict:
-        toks = sum(len(r.out_tokens) for r in done)
+        """``counters()`` and the latency summaries over ``done``."""
         # zero-output requests never produced a first token: excluding
-        # them keeps the TTFT percentiles honest
-        ttfts = [r.ttft_s for r in done if r.out_tokens]
-        tpots = [r.tpot_s for r in done if len(r.out_tokens) > 1]
+        # them keeps the TTFT percentiles honest (request_latency)
+        return {"requests": len(done),
+                "new_tokens": sum(len(r.out_tokens) for r in done),
+                **self.counters(),
+                **latency_metrics([request_latency(r) for r in done])}
+
+    def counters(self) -> dict:
+        """The engine's counters and gauges: their cost does not grow
+        with the requests served, so a server can snapshot them every
+        tick."""
         return {
-            "requests": len(done),
-            "new_tokens": toks,
             "ticks": self.tick_count,
             "dispatches": self.dispatch_count,
             "decode_steps": self.decode_steps,
@@ -1374,7 +1446,13 @@ class Engine:
             "degraded_transitions": (self.degraded.transitions
                                      if self.degraded is not None else 0),
             "drained_requests": self.drain_count,
-            **latency_summary(ttfts, "ttft"),
-            **latency_summary(tpots, "tpot"),
+            # tracing: queue wait from arrival to first admission, the
+            # scans' useful slot-steps against scan length x batch, and
+            # the seconds of each tick span (engine_<phase>_s)
+            "queue_wait_s": self.queue_wait_s,
+            "admissions": self.admission_count,
+            "scan_slot_steps": self.scan_slot_steps,
+            "scan_slot_capacity": self.scan_slot_capacity,
+            **self.spans.seconds,
             **self.pool.metrics(),
         }

@@ -166,8 +166,9 @@ class CachePool:
         self._index: dict[tuple, int] = {}    # chain key -> block
         self._children: dict[int, set] = {}   # block -> registered children
         self._dirty = True
-        self._copy_fn = jax.jit(
-            lambda s, a, b: lm.copy_cache_block(s, self.cfg, a, b))
+        def pool_copy_block(s, a, b):
+            return lm.copy_cache_block(s, self.cfg, a, b)
+        self._copy_fn = jax.jit(pool_copy_block)
         # counters
         self.prefix_hits = 0           # admissions that reused >= 1 block
         self.prefix_hit_tokens = 0     # prompt tokens NOT re-prefilled
