@@ -481,6 +481,76 @@ def decode_paged_attention_fused_sm(q, k_new, v_new, k_pool, v_pool, cur_len,
                          check_vma=False)(*args)
 
 
+def paged_view(model_axis_size: int, batch: int, width: int,
+               n_blocks: int) -> str:
+    """Which KV a paged decode step attends over, from static shapes.
+
+    ``"sharded"`` on a model axis wider than one
+    (``patterns.decode_attn_paged``). On one device, ``"pool"`` when the
+    pool holds no more blocks than the tables' slice names (``batch``
+    slots x ``width`` columns): every slot reads the whole pool once, in
+    place. Otherwise ``"gather"``: each slot gathers its table's blocks.
+    The attention and the engine's ``attn_pool_view_dispatches`` counter
+    both ask this function, so they cannot disagree.
+    """
+    if model_axis_size > 1:
+        return "sharded"
+    return "pool" if n_blocks <= batch * width else "gather"
+
+
+def paged_decode_attention(q, k_pool, v_pool, cur_len, tables, scale,
+                           window: int | None = None, *,
+                           whole_pool: bool):
+    """Served single-device paged decode attention.
+
+    q: (B, H, D); k_pool/v_pool: (n_blocks, bs, KVH, D); cur_len: (B,)
+    lengths including this step's token; tables: (B, C) global block ids
+    (-1 = unallocated or reclaimed). Returns (B, H, D) in q's dtype.
+
+    ``whole_pool`` (:func:`paged_view`'s ``"pool"``) attends every slot
+    over the whole pool, shared by the batch and read in its stored
+    dtype: a slot sees the blocks its table holds, so a block two tables
+    share is valid for both. The scores are exact bf16 products
+    accumulated in float32, the softmax is float32, and PV runs at HIGH
+    precision, which splits the float32 softmax into two bf16 terms
+    against V, exact in bf16. The result matches
+    :func:`reference_paged_decode_attention` to float32 rounding, and a
+    slot with no valid position (cur_len 0) returns zeros. A masked row
+    still enters PV with weight 0, and 0 x NaN is NaN, so the pool must
+    hold only finite values: the single-device write in
+    ``attention.decode_attn_step`` zeroes non-finite K/V rows.
+
+    Otherwise each slot attends over its own table's blocks through the
+    oracle. A per-slot contraction at one query head per KV head is a
+    batched matvec, which XLA on TPU lowers to a float32 multiply-reduce
+    behind its own convert of the gathered view, bf16 or not, so the
+    oracle costs the same.
+    """
+    if not whole_pool:
+        return reference_paged_decode_attention(q, k_pool, v_pool, cur_len,
+                                                tables, scale, window)
+    B, H, D = q.shape
+    n_blocks, bs, KVH, _ = k_pool.shape
+    gpos, valid = paged_block_positions(tables, n_blocks, 0, bs)
+    scores = jnp.einsum("bkgd,skd->bkgs", q.reshape(B, KVH, H // KVH, D),
+                        k_pool.reshape(-1, KVH, D),
+                        preferred_element_type=jnp.float32)
+    cl = jnp.asarray(cur_len)[:, None, None]
+    valid = valid[:, :, None] & (gpos < cl)
+    if window is not None:
+        valid = valid & (gpos >= cl - window)
+    valid = valid.reshape(B, 1, 1, -1)
+    scores = jnp.where(valid, scores * scale, -jnp.inf)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    p = jnp.exp(scores - jnp.where(jnp.isfinite(m), m, 0.0))
+    l = jnp.sum(p, axis=-1)
+    o = jnp.einsum("bkgs,skd->bkgd", p, v_pool.reshape(-1, KVH, D),
+                   precision=lax.Precision.HIGH,
+                   preferred_element_type=jnp.float32)
+    o = o / jnp.maximum(l, 1e-30)[..., None]
+    return o.reshape(B, H, D).astype(q.dtype)
+
+
 def reference_paged_decode_attention(q, k_pool, v_pool, cur_len, tables,
                                      scale, window: int | None = None):
     """Single-device paged oracle: gather the logical view, then dense

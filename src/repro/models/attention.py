@@ -185,6 +185,11 @@ def apply_attn(params, x, cfg, *, positions=None, dense_threshold=2048):
 
 
 # --------------------------------------------------------------- decode step
+def _finite(x):
+    """x with its non-finite entries zeroed."""
+    return jnp.where(jnp.isfinite(x), x, jnp.zeros_like(x))
+
+
 def decode_attn_step(params, x, cache, cur_len, cfg, active=None,
                      block_tables=None, bounded: bool = True):
     """One-token decode. x: (B, 1, d); cache: dict(k, v) strided seq-sharded
@@ -237,20 +242,25 @@ def decode_attn_step(params, x, cache, cur_len, cfg, active=None,
         act = (jnp.ones((B,), bool) if active is None
                else jnp.asarray(active))
         scale = 1.0 / (hd ** 0.5)
-        if W > 1:
+        view = fd.paged_view(W, B, block_tables.shape[1],
+                             cache["k"].shape[0])
+        if view == "sharded":
             o, ck, cv = patterns.decode_attn_paged(
                 q[:, 0], k[:, 0], v[:, 0], cache["k"], cache["v"], cl_b,
                 block_tables, scale=scale, window=cfg.sliding_window,
                 active=act, bounded=bounded)
         else:
             with jax.named_scope("kv_write"):
-                ck = fd.paged_write(cache["k"], k[:, 0], block_tables, cl_b,
-                                    act)
-                cv = fd.paged_write(cache["v"], v[:, 0], block_tables, cl_b,
-                                    act)
-            o = fd.reference_paged_decode_attention(
+                # every slot contracts the pool's rows, a masked row with
+                # weight 0, and 0 x NaN is NaN: a slot whose state went
+                # non-finite writes zeros, so only its own output fails
+                ck = fd.paged_write(cache["k"], _finite(k[:, 0]),
+                                    block_tables, cl_b, act)
+                cv = fd.paged_write(cache["v"], _finite(v[:, 0]),
+                                    block_tables, cl_b, act)
+            o = fd.paged_decode_attention(
                 q[:, 0], ck, cv, cl_b, block_tables, scale,
-                window=cfg.sliding_window)
+                window=cfg.sliding_window, whole_pool=view == "pool")
         o = o.reshape(B, 1, H * hd)
         out = patterns.project_k_sharded(o, params["wo"])
         return out, {"k": ck, "v": cv}

@@ -284,7 +284,9 @@ def decode_step(params, token, state, cfg, active=None,
     table width (no recompile coupling, maximum work). The returned
     state always carries the FULL table. ``bounded`` selects the
     distributed paged work model (table-gather vs masked-pool oracle);
-    single-device paged decode always gathers."""
+    single-device paged decode reads the whole pool once when it holds
+    at most batch x gather_width blocks, and gathers the table slice
+    otherwise (``flash_decode.paged_view``)."""
     ctx = dctx.current()
     if active is None:
         cur_len = state["cur_len"] + 1        # includes the new token

@@ -207,6 +207,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.flash_decode import paged_view
 from repro.distributed import context as dctx
 from repro.distributed.fault_tolerance import StragglerWatchdog
 from repro.models import lm
@@ -396,6 +397,9 @@ class Engine:
         self.mixed_dispatch_count = 0
         self.mixed_prompt_token_count = 0
         self.mixed_decode_token_count = 0
+        # dispatches whose paged attention reads the whole pool, not a
+        # per-slot gather (flash_decode.paged_view)
+        self.pool_view_dispatch_count = 0
         self._seq = 0               # submission order stamp
         self.bounded_gather = bool(bounded_gather)
         # -------- robustness plane (docs/robustness.md) --------------
@@ -425,6 +429,15 @@ class Engine:
         self.scan_slot_steps = 0
         self.scan_slot_capacity = 0
         self._build_dispatchers()
+
+    def _count_dispatch(self, gw: int):
+        """Count one dispatch at static gather width ``gw``, and whether
+        its paged attention reads the whole pool (``paged_view``)."""
+        self.dispatch_count += 1
+        if not self.cfg.is_attention_free and paged_view(
+                self.ctx.model_axis_size, self.batch, gw,
+                self.pool.n_blocks) == "pool":
+            self.pool_view_dispatch_count += 1
 
     def _build_dispatchers(self):
         """(Re)build the jitted dispatch closures. They capture
@@ -832,7 +845,7 @@ class Engine:
         # little prefill left don't pay the full chunk, while compile
         # count stays bounded at log2(prefill_chunk)
         cw = 1 if cmax <= 1 else pow2_bucket(cmax, C)
-        self.dispatch_count += 1
+        self._count_dispatch(gw)
         if not any_prefill:
             self.decode_dispatch_count += 1
         self.scan_slot_steps += int(cnt.sum())
@@ -968,7 +981,7 @@ class Engine:
         # K) so ragged tail megaticks don't pay the full K while jit
         # specializations stay bounded at log2(decode_steps)
         kb = pow2_bucket(kmax, K)
-        self.dispatch_count += 1
+        self._count_dispatch(gw)
         self.decode_dispatch_count += 1
         self.scan_slot_steps += int(budgets.sum())
         self.scan_slot_capacity += kb * self.batch
@@ -1130,7 +1143,7 @@ class Engine:
         # megatick token quota: jit specializations stay bounded at
         # log2(M) while ragged ticks don't pay the full quota
         S = pow2_bucket(nmax, M)
-        self.dispatch_count += 1
+        self._count_dispatch(gw)
         self.mixed_dispatch_count += 1
         self.mixed_prompt_token_count += int(pl.sum())
         self.scan_slot_steps += int(tot.sum())
@@ -1413,6 +1426,9 @@ class Engine:
             "mixed_dispatches": self.mixed_dispatch_count,
             "mixed_prompt_tokens": self.mixed_prompt_token_count,
             "mixed_decode_tokens": self.mixed_decode_token_count,
+            # single-device dispatches whose attention read the whole KV
+            # pool once instead of gathering a per-slot view
+            "attn_pool_view_dispatches": self.pool_view_dispatch_count,
             # the open-loop gate quantity: ALL fused decode-capable
             # dispatches (pure megaticks + mixed megaticks) per decode
             # token emitted — <= 1/K at steady state even with prefill

@@ -13,6 +13,7 @@ end to end; this file is the fast-tier net under it:
   bounded-vs-masked check (``check_paged_bounded_gather_bsp_small``)
   into the per-PR tier.
 """
+import functools
 import os
 import subprocess
 import sys
@@ -20,6 +21,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config, smoke_config
 from repro.core import flash_decode as fd
@@ -106,6 +108,143 @@ def test_slot_at_exact_gather_width():
         np.testing.assert_array_equal(np.asarray(ck), np.asarray(kp_ref))
         outs[width] = np.asarray(out)
     np.testing.assert_allclose(outs[4], outs[6], rtol=1e-6, atol=1e-6)
+
+
+# Served single-device attention against the gathered float32 oracle,
+# on both sides of the view rule: 4 slots, table width 4, blocks of 4,
+# so a pool of 8 blocks is attended whole and one of 40 is gathered.
+# Slot 0 shares its first block with slot 1 (a prefix hit); slot 2 is
+# inactive (no write, its length unchanged); slot 3 is empty (length 0).
+# Windowed cases hole slot 0's first column out of the window (-1, as
+# sliding-window reclaim leaves it).
+_SERVED_CASES = {
+    # id: (kv heads of 4 query heads, pool blocks, window, pool dtype)
+    "mha-whole-pool": (4, 8, None, jnp.bfloat16),
+    "mha-gathered": (4, 40, None, jnp.bfloat16),
+    "gqa-whole-pool": (2, 8, None, jnp.bfloat16),
+    "gqa-gathered": (1, 40, None, jnp.bfloat16),
+    "window-holes-whole-pool": (2, 8, 6, jnp.bfloat16),
+    "window-holes-gathered": (2, 40, 6, jnp.bfloat16),
+    "f32-pool-whole-pool": (2, 8, None, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(_SERVED_CASES))
+def test_served_paged_attention_matches_oracle(case):
+    KVH, n_blocks, window, dtype = _SERVED_CASES[case]
+    B, H, D, bs = 4, 4, 8, 4
+    first = -1 if window is not None else 5
+    tables = jnp.array([[first, 2, 7, -1], [5, 3, -1, -1],
+                        [1, 6, -1, -1], [-1, -1, -1, -1]], jnp.int32)
+    active = jnp.array([True, True, False, False])
+    cur = jnp.array([11, 6, 7, 0], jnp.int32)
+    view = fd.paged_view(1, B, tables.shape[1], n_blocks)
+    assert view == ("pool" if case.endswith("whole-pool") else "gather")
+    # q holds bf16 values in float32, so the outputs compare in float32
+    q = _rand(0, (B, H, D)).astype(jnp.bfloat16).astype(jnp.float32)
+    k_pool = _rand(1, (n_blocks, bs, KVH, D)).astype(dtype)
+    v_pool = _rand(2, (n_blocks, bs, KVH, D)).astype(dtype)
+    k_pool = fd.paged_write(k_pool, _rand(3, (B, KVH, D)), tables, cur,
+                            active)
+    v_pool = fd.paged_write(v_pool, _rand(4, (B, KVH, D)), tables, cur,
+                            active)
+    want = fd.reference_paged_decode_attention(q, k_pool, v_pool, cur,
+                                               tables, 0.25, window=window)
+    # the rule's view, then the whole pool where the rule would gather
+    # (most of its blocks unreferenced by any table)
+    for whole in dict.fromkeys((view == "pool", True)):
+        got = jax.jit(functools.partial(
+            fd.paged_decode_attention, scale=0.25, window=window,
+            whole_pool=whole))(q, k_pool, v_pool, cur, tables)
+        assert got.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(got[:3]),
+                                   np.asarray(want[:3]),
+                                   rtol=2e-4, atol=2e-4)
+        if whole:
+            # no position to attend: zeros, where the oracle averages
+            # garbage
+            np.testing.assert_array_equal(np.asarray(got[3]), 0.0)
+
+
+def test_attend_whole_pool_boundary():
+    """The view rule flips where the pool outgrows the blocks the
+    tables' slice names: at the chat cell's batch 16 and 256 blocks,
+    widths 16 and up read the pool, 8 and below gather; any model axis
+    wider than one takes the sharded path."""
+    assert fd.paged_view(1, 16, 16, 256) == "pool"       # n == B*C
+    assert fd.paged_view(1, 16, 16, 257) == "gather"     # B*C one below
+    assert fd.paged_view(1, 16, 16, 255) == "pool"
+    assert fd.paged_view(1, 16, 8, 256) == "gather"
+    assert fd.paged_view(1, 16, 100, 256) == "pool"
+    assert fd.paged_view(1, 1, 1, 2) == "gather"
+    assert fd.paged_view(1, 1, 1, 1) == "pool"
+    assert fd.paged_view(4, 16, 100, 256) == "sharded"
+    assert fd.paged_view(2, 1, 1, 1000) == "sharded"
+
+
+@pytest.mark.parametrize("n_blocks", [8, 40], ids=["whole-pool", "gathered"])
+def test_nonfinite_slot_fails_alone(n_blocks):
+    """One slot's hidden state goes NaN in a single-device paged decode
+    step (docs/robustness.md, per-request error isolation). Its own
+    output is non-finite; the pool stays finite; the other slots'
+    outputs equal those of the same step with that slot's state finite;
+    and so do theirs on the next step, with the victim retired and its
+    blocks left in the pool as they were written."""
+    from repro.models import attention
+    cfg, params = _setup()
+    ap = jax.tree.map(lambda a: a[0], params["backbone"]["layers"]["attn"])
+    B, bs = 4, 4
+    tables = jnp.array([[5, 2, -1, -1], [5, 3, -1, -1],
+                        [1, 6, -1, -1], [0, 4, -1, -1]], jnp.int32)
+    assert fd.paged_view(1, B, tables.shape[1], n_blocks) == \
+        ("pool" if n_blocks == 8 else "gather")
+    shape = (n_blocks, bs, cfg.n_kv_heads, cfg.hd)
+    cache = {"k": _rand(1, shape).astype(jnp.bfloat16),
+             "v": _rand(2, shape).astype(jnp.bfloat16)}
+    cur = jnp.array([6, 7, 5, 8], jnp.int32)
+    x = _rand(3, (B, 1, cfg.d_model)).astype(jnp.bfloat16)
+    step = jax.jit(lambda x, c, cur, act: attention.decode_attn_step(
+        ap, x, c, cur, cfg, active=act, block_tables=tables))
+    act = jnp.ones((B,), bool)
+    want, c_ok = step(x, cache, cur, act)
+    got, c_bad = step(x.at[1].set(jnp.nan), cache, cur, act)
+    others = np.array([0, 2, 3])
+    assert not np.isfinite(np.asarray(got[1], np.float32)).any()
+    for leaf in (c_bad["k"], c_bad["v"]):
+        assert np.isfinite(np.asarray(leaf, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got[others], np.float32),
+                                  np.asarray(want[others], np.float32))
+    # the next step: the victim is retired, the others write and attend
+    act = jnp.array([True, False, True, True])
+    cur = cur + act
+    x = _rand(4, (B, 1, cfg.d_model)).astype(jnp.bfloat16)
+    want, _ = step(x, c_ok, cur, act)
+    got, _ = step(x, c_bad, cur, act)
+    np.testing.assert_array_equal(np.asarray(got[others], np.float32),
+                                  np.asarray(want[others], np.float32))
+
+
+def test_engine_counts_whole_pool_dispatches_token_identity():
+    """A single-device engine whose gather width grows across the rule:
+    early dispatches gather, later ones read the whole pool and are
+    counted in ``attn_pool_view_dispatches``, and every greedy stream
+    matches the solo decode reference."""
+    cfg, params = _setup()
+    rng = np.random.default_rng(3)
+    prompts = [[int(t) for t in rng.integers(1, cfg.vocab_size, n)]
+               for n in (5, 21)]
+    eng = Engine(params, cfg, batch=2, max_len=64, prefill_chunk=8,
+                 block_size=4, n_blocks=16)
+    for i, p in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=list(p), max_new_tokens=10))
+    done = []
+    while eng.queue or eng.active:
+        done.extend(eng.tick())
+    whole = eng.counters()["attn_pool_view_dispatches"]
+    assert 0 < whole < eng.dispatch_count, (whole, eng.dispatch_count)
+    for r in done:
+        want = reference_generate(params, cfg, r.prompt, 10, 64)
+        assert r.out_tokens == want, (r.rid, r.out_tokens, want)
 
 
 def test_pow2_bucket_contract():

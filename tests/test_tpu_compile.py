@@ -15,6 +15,7 @@ process at a time may load the TPU library, and pytest's workers all
 import every test file.
 """
 import os
+import re
 
 import pytest
 
@@ -42,9 +43,9 @@ def topo():
     jax.config.update("jax_enable_compilation_cache", enabled)
 
 
-def _compile(topo, tp: int, mode: str, program: str):
-    """Lower + compile one megatick program for ``tp`` described chips.
-    Returns the compiled executable."""
+def _compile(topo, tp: int, mode: str, program: str, gw: int = 8):
+    """Lower + compile one megatick program for ``tp`` described chips
+    at static gather width ``gw``. Returns the compiled executable."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -90,7 +91,6 @@ def _compile(topo, tp: int, mode: str, program: str):
     eng._base_key = jax.random.PRNGKey(0)
     eng._build_dispatchers()
     f32 = jnp.float32
-    gw = 8
     if program == "mixed":
         S = M
         lowered = eng._stepM.lower(
@@ -119,3 +119,34 @@ def test_megatick_compiles_for_v5e(topo, tp, mode, program):
         assert "collective-permute" in hlo
     else:
         assert "all-reduce" in hlo or "reduce-scatter" in hlo
+
+
+@pytest.mark.parametrize("gw", [4, 16])
+def test_single_chip_attention_reads_kv_in_bf16(topo, gw):
+    """One chip, on both sides of ``flash_decode.paged_view`` (the pool
+    holds B x 16 blocks: width 16 reads the whole pool, 4 gathers). A
+    float32 array of KV rows (a dim of head_dim and at least a block per
+    slot of elements) is what a widened view looks like. The whole-pool
+    program has none: the pool is read as stored. The gathered program
+    has it: its per-slot matvec becomes a float32 multiply-reduce behind
+    a convert of the view, which is why that side keeps the oracle."""
+    from repro.configs import get_config
+    from repro.core.flash_decode import paged_view
+
+    view = paged_view(1, B, gw, B * MAX_LEN // BLOCK)
+    assert view == ("pool" if gw == 16 else "gather")
+    cfg = get_config("phi3-mini-3.8b")
+    kv_rows = B * BLOCK * cfg.n_kv_heads * cfg.hd
+    widened = []
+    for m in re.finditer(r"= f32\[([0-9,]+)\]",
+                         _compile(topo, 1, "auto", "mixed", gw).as_text()):
+        dims = [int(d) for d in m.group(1).split(",")]
+        n = 1
+        for d in dims:
+            n *= d
+        if cfg.hd in dims and n >= kv_rows:
+            widened.append(m.group(0))
+    if view == "pool":
+        assert not widened, sorted(set(widened))
+    else:
+        assert widened
